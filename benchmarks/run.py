@@ -26,6 +26,9 @@ def main() -> None:
                          "store,obs,search")
     args = ap.parse_args()
 
+    from .compile_cache import use_compile_cache
+    use_compile_cache()
+
     from . import paper_figures as pf
 
     sections = args.sections.split(",") if args.sections != "all" else [

@@ -23,9 +23,10 @@ algorithm — vectorized galloping for array x array, bit probes for
 array x bitmap (no domain lift), fused word-op + popcount for
 bitmap x bitmap, gallop-in-ranges for array x run, range-mask coverage for
 run x bitmap, and a run-domain merge for run x run that never materializes
-bits at all. On TPU the routing is a ``@pl.when``-tagged Pallas kernel
-(``repro.kernels.roaring``) generated from the same table; the XLA reference
-computes the same cheap paths cond-guarded per class. Output
+bits at all. The XLA reference computes these paths cond-guarded per
+class; on TPU ``repro.kernels.roaring`` lifts the rows to bitmap domain and
+runs the word AND in its Pallas kernel, applying the same table's output
+semantics. Output
 canonicalization is *best-of-three* (``runOptimize``: array vs bitmap vs run
 by serialized size) and *lazy*: only bitmap-domain rows whose canonical form
 is packed (array or run) pay the O(2^16) extraction, and those passes are
@@ -144,17 +145,10 @@ def empty(capacity: int) -> RoaringSlab:
 # =============================================================================
 
 def row_array_to_bits(row: jax.Array, card: jax.Array) -> jax.Array:
-    """Packed sorted u16 array row -> 4096-word bitmap row.
-
-    Distinct elements set distinct bits, so a scatter-add is an exact OR.
-    """
-    lo = row.astype(jnp.int32)
-    valid = jnp.arange(row.shape[0]) < card
-    word = jnp.where(valid, lo >> 4, ROW_WORDS)           # OOB index dropped
-    bit = (lo & 15).astype(jnp.uint16)
-    vals = jnp.where(valid, jnp.uint16(1) << bit, jnp.uint16(0))
-    return jnp.zeros((ROW_WORDS,), jnp.uint16).at[word].add(
-        vals, mode="drop")
+    """Packed sorted u16 array row -> 4096-word bitmap row (scatter-add:
+    distinct elements set distinct bits, so the add is an exact OR)."""
+    return _D.array_coverage_by_scatter(row.reshape(_D.ROW_SHAPE),
+                                        card).reshape(ROW_WORDS)
 
 
 def row_run_to_bits(row: jax.Array) -> jax.Array:
@@ -180,11 +174,10 @@ def row_nruns(row: jax.Array, kind: jax.Array) -> jax.Array:
 
 
 def row_to_bits(row: jax.Array, card: jax.Array, kind: jax.Array) -> jax.Array:
-    """Uniform bitmap-domain view of a container row (empty -> zeros).
-
-    Kind-dispatching lift: arrays scatter their packed values, runs scatter
-    their coverage (both O(4096)), bitmaps pass through.
-    """
+    """Uniform bitmap-domain view of ONE container row (empty -> zeros), by
+    the scatter lifts of ``dispatch.lift_rows``: arrays scatter their packed
+    values, runs their coverage (both O(4096)), bitmaps pass through. Rows
+    in bulk go through ``_lift_rows``."""
     as_bits = row_array_to_bits(row, card)
     lifted = jnp.where(kind == KIND_RUN, row_run_to_bits(row), as_bits)
     return jnp.where(kind == KIND_BITMAP, row, lifted) * (kind != KIND_EMPTY).astype(jnp.uint16)
@@ -531,7 +524,10 @@ def contains(slab: RoaringSlab, queries: jax.Array) -> jax.Array:
             return (jnp.where(go_right, mid + 1, l),
                     jnp.where(go_right, h, mid))
 
-        l, _ = jax.lax.fori_loop(0, 13, body, (jnp.int32(0), card))
+        # carries derived from the probe and the slab, so they vary with
+        # both under shard_map
+        zero = jnp.zeros_like(lo_i + card)
+        l, _ = jax.lax.fori_loop(0, 13, body, (zero, zero + card))
         probe = slab.data[row_i, jnp.clip(l, 0, ROW_WORDS - 1)].astype(
             jnp.int32)
         arr_hit = (l < card) & (probe == lo_i)
@@ -555,8 +551,7 @@ def contains(slab: RoaringSlab, queries: jax.Array) -> jax.Array:
             return (jnp.where(go_right, mid + 1, l),
                     jnp.where(open_ & ~go_right, mid, h))
 
-        rl, _ = jax.lax.fori_loop(0, 12, rbody,
-                                  (jnp.int32(0), jnp.int32(MAX_RUNS)))
+        rl, _ = jax.lax.fori_loop(0, 12, rbody, (zero, zero + MAX_RUNS))
         ri = jnp.clip(rl - 1, 0, MAX_RUNS - 1)
         rs = slab.data[row_i, 2 * ri].astype(jnp.int32)
         rln = slab.data[row_i, 2 * ri + 1].astype(jnp.int32)
@@ -641,8 +636,8 @@ def slab_select(slab: RoaringSlab, j: jax.Array) -> jax.Array:
 # set algebra: hybrid per-kind dispatch (paper S4)
 #
 # Key-aligned container pairs are classified by (kind_a, kind_b) and routed
-# through the matching algorithm via repro.kernels.roaring (Pallas @pl.when
-# on TPU, XLA reference elsewhere). Canonicalization is lazy: only
+# through the matching algorithm via repro.kernels.roaring (Pallas word-op
+# kernel on TPU, XLA reference elsewhere). Canonicalization is lazy: only
 # bitmap-domain output rows that land back under the 4096 threshold pay the
 # O(2^16) extraction, and the pass is lax.cond-guarded so it is skipped at
 # runtime when no row needs it. The pre-dispatch bitmap-domain formulation is
@@ -836,7 +831,6 @@ def _run_merge_rows_lazy(da, db, rr):
     """Cond-guarded batched run-merge over the rows classified run x run.
     Returns (pairs, card, n_out, bits_fallback) — the coverage-bits fallback
     is itself guarded and only materializes for overflowing rows."""
-    C = da.shape[0]
 
     def merge(args):
         da, db = args
@@ -854,14 +848,13 @@ def _run_merge_rows_lazy(da, db, rr):
                 jnp.where(o, db, jnp.uint16(0xFFFF)))
 
         bits = jax.lax.cond(jnp.any(overflow), cov,
-                            lambda args: jnp.zeros((C, ROW_WORDS), jnp.uint16),
-                            (da, db))
+                            lambda args: jnp.zeros_like(args[0]), (da, db))
         return pairs, card, n_out, bits
 
     def skip(args):
-        return (jnp.full((C, ROW_WORDS), 0xFFFF, jnp.uint16),
-                jnp.zeros((C,), jnp.int32), jnp.zeros((C,), jnp.int32),
-                jnp.zeros((C, ROW_WORDS), jnp.uint16))
+        da, _ = args
+        zero = jnp.zeros_like(da[:, 0], dtype=jnp.int32)
+        return (jnp.full_like(da, 0xFFFF), zero, zero, jnp.zeros_like(da))
 
     return jax.lax.cond(jnp.any(rr), merge, skip, (da, db))
 
@@ -930,7 +923,7 @@ def _finalize(keys, card, form, arr_rows, bits_rows, runs_rows, runs_nr):
 
 def _dispatch_meta(ka, kb, ca, cb, ra=None, rb=None) -> jax.Array:
     """Interleave (kind_a, kind_b, card_a, card_b, nruns_a, nruns_b) per row
-    -> i32[6C] (the registry's scalar-prefetch contract)."""
+    -> i32[6C] (the registry's dispatch meta contract)."""
     if ra is None:
         ra = jnp.zeros_like(ka)
     if rb is None:
@@ -984,7 +977,6 @@ def _or_rows(da, ca, ka, db, cb, kb, *, word_op=jnp.bitwise_or,
     inspects cards of array-ish rows (whose merge cards stay exact) — and
     the root recounts via ``_recount_bitmap_rows`` before finalization.
     """
-    M = ka.shape[0]
 
     def merge_pass(args):
         da, ca, db, cb = args
@@ -992,19 +984,19 @@ def _or_rows(da, ca, ka, db, cb, kb, *, word_op=jnp.bitwise_or,
             functools.partial(_row_merge_sparse, xor=xor))(da, ca, db, cb)
 
     def merge_skip(args):
-        return (jnp.full((M, ROW_WORDS), 0xFFFF, jnp.uint16),
-                jnp.zeros((M,), jnp.int32))
+        da, ca, _, _ = args
+        return jnp.full_like(da, 0xFFFF), jnp.zeros_like(ca)
 
     def bitmap_pass(args):
         da, ca, ka, db, cb, kb = args
         out = word_op(_lift_rows(da, ca, ka), _lift_rows(db, cb, kb))
         if defer_card:
-            return out, jnp.full((M,), CHUNK_SIZE, jnp.int32)
+            return out, jnp.full_like(ca, CHUNK_SIZE)
         return out, jax.vmap(row_popcount)(out)
 
     def bitmap_skip(args):
-        return (jnp.zeros((M, ROW_WORDS), jnp.uint16),
-                jnp.zeros((M,), jnp.int32))
+        da, ca = args[0], args[1]
+        return jnp.zeros_like(da), jnp.zeros_like(ca)
 
     small, use_bitmap = _D.union_route(ka, kb, ca, cb, ARRAY_MAX)
     merge_rows, merge_card = jax.lax.cond(jnp.any(small), merge_pass,
@@ -1031,7 +1023,7 @@ def _recount_bitmap_rows(data, card, kind):
     cnt = jax.lax.cond(
         jnp.any(is_b),
         lambda m: jax.vmap(row_popcount)(m),
-        lambda m: jnp.zeros((data.shape[0],), jnp.int32), masked)
+        lambda m: jnp.zeros_like(card), masked)
     return jnp.where(is_b, cnt, card)
 
 
@@ -1068,7 +1060,6 @@ def _andnot_rows(da, ca, ka, db, cb, kb):
     <= card_a <= 4096, stays packed); bitmap- and run-A rows take the
     ``lax.cond``-guarded bitmap-domain pass with the cheap run lift.
     """
-    M = ka.shape[0]
     slot = jnp.arange(ROW_WORDS, dtype=jnp.int32)
     probe_a, lift_a = _D.andnot_route(ka, kb)
 
@@ -1093,8 +1084,8 @@ def _andnot_rows(da, ca, ka, db, cb, kb):
         return out, jax.vmap(row_popcount)(out)
 
     def bitmap_skip(args):
-        return (jnp.zeros((M, ROW_WORDS), jnp.uint16),
-                jnp.zeros((M,), jnp.int32))
+        da, ca = args[0], args[1]
+        return jnp.zeros_like(da), jnp.zeros_like(ca)
 
     rb = _rows_nruns(db, kb)
     keep = jax.vmap(probe_row)(da, ca, db, cb, kb, rb) & probe_a[:, None]
@@ -1234,22 +1225,14 @@ def slab_and_card_many(query: RoaringSlab,
 
 
 def _lift_rows(data, card, kind):
-    """Batched bitmap-domain view of raw rows, with a runtime fast path:
-    when every live row is already a word row (tree-reduction levels past
-    the first — union intermediates are bitmap-form by construction), the
-    kind-dispatching lift (array scatter + run coverage + selects) is
-    skipped wholesale by ``lax.cond`` and only the empty-row mask applies."""
-    need = (kind != KIND_BITMAP) & (kind != KIND_EMPTY)
-
-    def lift(args):
-        data, card, kind = args
-        return jax.vmap(row_to_bits)(data, card, kind)
-
-    def passthrough(args):
-        data, _, kind = args
-        return data * (kind != KIND_EMPTY)[:, None].astype(jnp.uint16)
-
-    return jax.lax.cond(jnp.any(need), lift, passthrough, (data, card, kind))
+    """Batched bitmap-domain view of raw rows u16[..., 4096]: the kind lift
+    of ``dispatch.lift_rows`` with the default device's array lift. The
+    array and run lifts each run only when some row needs them (tree
+    levels past the first hold bitmap rows only)."""
+    # the run lift reads the rows' pair padding, not a run count
+    words = _D.lift_rows(data, kind, card, jnp.zeros_like(kind),
+                         _D.device_array_lift())
+    return _D.from_words(words)
 
 
 def _row_merge_sparse(da, ca, db, cb, *, xor: bool):

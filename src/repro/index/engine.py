@@ -498,7 +498,7 @@ def execute(stack: Optional[RoaringSlab], expr: Optional[Expr] = None,
 
     ``fused=True`` evaluates the whole tree in ONE kernel launch
     (``kernels.roaring.fused``): leaves stream from HBM once, every
-    intermediate stays in VMEM scratch, and the per-op tree-reduce becomes
+    intermediate stays in VMEM, and the per-op tree-reduce becomes
     the fallback rung — same bytes out either way.
 
     ``backend`` picks the dispatch backend ("pallas" / "xla" / None=auto).
@@ -589,17 +589,20 @@ def _align_query(stack: RoaringSlab, query: SlabLike):
     return qd, qc, qk, jr._rows_nruns(qd, qk)
 
 
+@jax.jit
 def _stack_scores(data, card, kind, nruns, qd, qc, qk, qr):
-    """Per-slab |slab_n ∩ query| via the stacked batched-meta dispatch."""
+    """Per-slab |slab_n ∩ query| via the stacked batched-meta dispatch: the
+    query rows are shared by all N slabs, never broadcast in HBM, and the
+    dispatch's hit rows are dead code under this jit — only the fused
+    cardinalities are computed."""
     from repro.kernels.roaring import ops as _kops
     N, C = kind.shape
-    qdn = jnp.broadcast_to(qd, (N,) + qd.shape)
     meta = jnp.stack([
         kind, jnp.broadcast_to(qk, (N, C)),
         card, jnp.broadcast_to(qc, (N, C)),
         nruns, jnp.broadcast_to(qr, (N, C)),
     ], axis=2).reshape(N, 6 * C).astype(jnp.int32)
-    _, rc = _kops.intersect_dispatch_stacked(data, qdn, meta)
+    _, rc = _kops.intersect_dispatch_stacked(data, qd, meta)
     return jnp.sum(rc, axis=1)
 
 
@@ -629,14 +632,6 @@ def topk_by_card(stack: RoaringSlab, query: SlabLike, k: int):
 # sharding: slab axis across the device mesh, query replicated
 # =============================================================================
 
-def _shard_map():
-    try:                         # jax >= 0.4.35 exposes it at top level
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
 def batched_and_card_sharded(stack: RoaringSlab, query: SlabLike,
                              mesh, axis: str = "data") -> jax.Array:
     """``batched_and_card`` with the slab axis sharded over ``mesh[axis]``.
@@ -646,10 +641,12 @@ def batched_and_card_sharded(stack: RoaringSlab, query: SlabLike,
     cross-device traffic until the caller reduces the i32[N] scores).
     ``stack.n_slabs`` must divide evenly by the mesh axis size.
     """
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import NamedSharding, PartitionSpec as P
 
+    # the query may sit on one device (a service's cache); replicate it
+    query = jax.device_put(query, NamedSharding(mesh, P()))
     qd, qc, qk, qr = _align_query(stack, query)
-    f = _shard_map()(
+    f = jax.shard_map(
         _stack_scores, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(), P()),
         out_specs=P(axis))

@@ -1,22 +1,20 @@
 """The kind-dispatch engine: one declarative registry of per-pair-class row
-kernels, consumed by all three backends.
-
-PR 1 hard-coded the paper's 3-kind hybrid dispatch as ``(kind_a, kind_b)``
-branch chains in three places (``jax_roaring`` slab ops, the XLA reference,
-the ``@pl.when`` Pallas kernel). Adding the 2016 follow-up paper's run
-containers would have meant growing three hand-enumerated 3x3 grids to 4x4.
-Instead the grid now lives *here once*:
+kernels, consumed by the slab layer, the XLA reference and the Pallas path.
 
   * ``AND_TABLE`` — one ``PairClass`` row per live ``(kind_a, kind_b)`` pair,
     naming the row kernel, the output semantic the slab layer must apply, and
     whether the kernel sees the operands swapped;
-  * ``make_and_kernels(coverage)`` — the row-kernel implementations, written
-    against gather-only jnp so the *same functions* run inside the Pallas
-    kernel body (``@pl.when``-selected) and vmapped in the XLA reference
-    (mask-selected). The only backend-specific piece is how a run row is
-    lifted to its coverage bitmap: the XLA side scatters (cheap,
-    O(n_runs + 4096)), the Pallas side binary-searches the run list per bit
-    position (gather-only); both produce bit-identical coverage;
+  * ``make_and_kernels()`` — the row-kernel implementations of the XLA
+    reference (galloping, bit probes, word AND, gallop-in-ranges, run
+    coverage), vmapped per row and selected per class by mask;
+  * ``lift_rows`` — THE kind -> bitmap-domain lift, of every word-op
+    evaluation: the slab layer's OR / ANDNOT pipelines, the XLA reference
+    and the Pallas word-op kernel. Array rows are lifted by scatter or by
+    one-hot matrix products (``ARRAY_LIFTS``; the caller names one, and
+    ``device_array_lift`` picks per device); on the chip every row is lifted first,
+    the kernel then runs AND / OR / ANDNOT and the popcount over u32 words,
+    and the ``mask_*`` classes probe the result at the array side's values
+    (``k_probe``), so both backends return the same bytes;
   * union / andnot routing policy (``union_route`` / ``andnot_route``) so the
     slab layer's OR/XOR/ANDNOT pipelines classify from the same table.
 
@@ -35,16 +33,14 @@ Output semantics (``PairClass.out``):
 ``run x run`` is special-cased by the slab layer: the registry routes it to
 the *run-merge* row kernel (``slab_route == 'run_merge'``), a scatter/argsort
 formulation that stays entirely in run domain (``jax_roaring._run_merge_row``)
-— the in-kernel ``run_cov_and`` (coverage AND + fused popcount) is the
-Pallas/ref formulation of the same class, kept bit-identical for the
-tri-backend tests and for TPU contexts where the kernel output is consumed
-directly.
+— the ``run_cov_and`` kernel (coverage AND + popcount) is the dispatch
+formulation of the same class, kept bit-identical for the backend tests and
+for callers that consume the dispatch output directly.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Callable, Dict, Tuple
 
 import jax
@@ -52,6 +48,7 @@ import jax.numpy as jnp
 
 ROW_WORDS = 4096
 ROW_SHAPE = (32, 128)          # u16[32,128] == one 8 kB container row
+WORD_SHAPE = (16, 128)         # u32[16,128] == the same row as 32-bit words
 MAX_RUNS = ROW_WORDS // 2      # (start, length-1) u16 pairs per row
 
 KIND_EMPTY = 0
@@ -63,13 +60,13 @@ KIND_RUN = 3
 # and the repro.index engine). Documented in docs/API.md; tests/test_docs.py
 # asserts the two stay in sync.
 __all__ = [
-    "ROW_WORDS", "ROW_SHAPE", "MAX_RUNS",
+    "ROW_WORDS", "ROW_SHAPE", "WORD_SHAPE", "MAX_RUNS",
     "KIND_EMPTY", "KIND_ARRAY", "KIND_BITMAP", "KIND_RUN",
     "PairClass", "AND_TABLE", "class_predicate", "out_mask", "route_mask",
     "union_route", "andnot_route",
-    "coverage_by_search", "coverage_by_scatter", "make_and_kernels",
-    "array_coverage_by_search", "array_coverage_by_scatter",
-    "make_lift_kernels",
+    "coverage_by_scatter", "array_coverage_by_scatter",
+    "array_coverage_by_matmul", "ARRAY_LIFTS", "on_tpu", "device_array_lift",
+    "make_and_kernels", "lift_rows", "to_words", "from_words",
     "bind_args", "META_FIELDS", "unpack_meta",
 ]
 
@@ -148,7 +145,7 @@ def andnot_route(ka, kb):
 
 
 # =============================================================================
-# shared row kernels (gather-only jnp: Pallas-body and vmap compatible)
+# row kernels of the XLA reference (vmapped per container row)
 # =============================================================================
 
 def _flat_pos():
@@ -164,8 +161,8 @@ def _take_flat(row, idx):
 def _run_upper_bound(run_row, n_runs, p):
     """#run-starts <= p, searching the packed (start, len-1) pairs at even
     slots. 12 halvings resolve a window of up to 2048 runs."""
-    lo = jnp.zeros_like(p)
-    hi = jnp.full_like(p, n_runs)
+    lo = jnp.zeros_like(p + n_runs)     # varies with both under shard_map
+    hi = lo + n_runs
 
     def body(_, lohi):
         lo, hi = lohi
@@ -191,24 +188,9 @@ def _run_covered(run_row, n_runs, p):
     return (idx >= 0) & (p <= s + l)
 
 
-def coverage_by_search(run_row, n_runs):
-    """Run row -> coverage bitmap tile, gather-only (the Pallas-side lift):
-    each of the 2^16 bit positions asks ``_run_covered`` via 16 lane-parallel
-    passes over the (32,128) word tile."""
-    word = _flat_pos()
-
-    def bit_body(j, cov):
-        covered = _run_covered(run_row, n_runs, word * 16 + j)
-        return cov | (covered.astype(jnp.uint16) << j)
-
-    return jax.lax.fori_loop(0, 16, bit_body,
-                             jnp.zeros(ROW_SHAPE, jnp.uint16))
-
-
 def coverage_by_scatter(run_row, n_runs):
     """Run row -> coverage bitmap tile via difference-array scatter,
-    O(n_runs + ROW_WORDS) (the XLA-side lift). Bit-identical to
-    ``coverage_by_search``; not Pallas-lowerable (scatter)."""
+    O(n_runs + ROW_WORDS)."""
     flat = run_row.reshape(ROW_WORDS)
     pairs = flat.reshape(MAX_RUNS, 2).astype(jnp.int32)
     s, l = pairs[:, 0], pairs[:, 1]
@@ -232,71 +214,141 @@ def coverage_by_scatter(run_row, n_runs):
     return (partial | full).astype(jnp.uint16).reshape(ROW_SHAPE)
 
 
-def array_coverage_by_search(arr_row, card):
-    """Packed sorted array row -> membership bitmap tile, gather-only (the
-    Pallas-side lift for the fused tree evaluator): each of the 2^16 bit
-    positions lower_bounds the array's packed prefix — 16 lane-parallel
-    passes of 13 halvings over the (32,128) word tile. Bit-identical to
-    ``array_coverage_by_scatter``."""
-    word = _flat_pos()
-
-    def contains(p):
-        lo = jnp.zeros(ROW_SHAPE, jnp.int32)
-        hi = jnp.full(ROW_SHAPE, card, jnp.int32)
-
-        def body(_, lohi):
-            lo, hi = lohi
-            mid = (lo + hi) // 2
-            vals = _take_flat(arr_row, jnp.clip(mid, 0, ROW_WORDS - 1)).astype(
-                jnp.int32)
-            go_right = vals < p
-            return (jnp.where(go_right, mid + 1, lo),
-                    jnp.where(go_right, hi, mid))
-
-        lo, _ = jax.lax.fori_loop(0, 13, body, (lo, hi))
-        found = _take_flat(arr_row, jnp.clip(lo, 0, ROW_WORDS - 1)).astype(
-            jnp.int32) == p
-        return found & (lo < card)
-
-    def bit_body(j, cov):
-        return cov | (contains(word * 16 + j).astype(jnp.uint16) << j)
-
-    return jax.lax.fori_loop(0, 16, bit_body,
-                             jnp.zeros(ROW_SHAPE, jnp.uint16))
-
-
 def array_coverage_by_scatter(arr_row, card):
-    """Packed sorted array row -> membership bitmap tile via one-hot word
-    scatter, O(4096) (the XLA-side lift). Values are distinct, so each bit
-    is contributed exactly once; not Pallas-lowerable (scatter)."""
-    flat = arr_row.reshape(ROW_WORDS).astype(jnp.int32)
+    """Packed sorted array row -> membership bitmap tile by scatter-add,
+    O(ROW_WORDS). Distinct elements set distinct bits, so the add is an
+    exact OR."""
+    lo = arr_row.reshape(ROW_WORDS).astype(jnp.int32)
     valid = jnp.arange(ROW_WORDS) < card
-    words = jnp.zeros((ROW_WORDS,), jnp.int32)
-    words = words.at[jnp.where(valid, flat >> 4, ROW_WORDS)].add(
-        1 << (flat & 15), mode="drop")
-    return words.astype(jnp.uint16).reshape(ROW_SHAPE)
+    word = jnp.where(valid, lo >> 4, ROW_WORDS)           # OOB index dropped
+    vals = jnp.where(valid, jnp.uint16(1) << (lo & 15).astype(jnp.uint16),
+                     jnp.uint16(0))
+    return jnp.zeros((ROW_WORDS,), jnp.uint16).at[word].add(
+        vals, mode="drop").reshape(ROW_SHAPE)
 
 
-def make_lift_kernels(coverage: Callable,
-                      array_coverage: Callable) -> Dict[int, Callable]:
-    """Bind the kind -> bitmap-domain lift table to backend-specific run /
-    array coverage implementations.
+def array_coverage_by_matmul(arr_rows, card):
+    """Packed sorted array rows -> membership bitmap rows, as one-hot
+    matrix products (batched over rows; the MXU does the placing that a
+    scatter would do element by element).
 
-    Every lift: ``fn(row, card, n_runs) -> bits u16[32,128]`` — the row's
-    membership bitmap regardless of its stored kind. This is the leaf-load
-    step of the fused tree evaluator: once every operand is in bitmap
-    domain, the whole expression is word ops.
+    arr_rows: u16[R, 32, 128]; card: i32[R]. A value ``v`` lands in word
+    ``w = v >> 4`` at bit ``v & 15``; writing ``w = 128 r + l``, the words
+    are ``W[r, l] = sum_j [r_j = r] 2^(b_j) [l_j = l]`` — the product of a
+    ``32 x 4096`` and a ``4096 x 128`` one-hot matrix per row. Exact: the
+    entries are powers of two (exact in bf16) and each output sums at most
+    16 distinct ones (< 2^24, exact in the f32 accumulator).
     """
-    return {
-        KIND_EMPTY: lambda row, c, r: jnp.zeros(ROW_SHAPE, jnp.uint16),
-        KIND_ARRAY: lambda row, c, r: array_coverage(row, c),
-        KIND_BITMAP: lambda row, c, r: row.astype(jnp.uint16),
-        KIND_RUN: lambda row, c, r: coverage(row, r),
-    }
+    R = arr_rows.shape[0]
+    v = arr_rows.reshape(R, 1, ROW_WORDS).astype(jnp.int32)
+    valid = jnp.arange(ROW_WORDS)[None, None, :] < card[:, None, None]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (1, ROW_SHAPE[0], 1), 1)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (1, ROW_SHAPE[1], 1), 1)
+    a = jnp.where(valid & ((v >> 11) == rows),
+                  (1 << (v & 15)).astype(jnp.float32), 0.0)
+    b = ((v >> 4) & (ROW_SHAPE[1] - 1)) == lanes
+    words = jnp.einsum("rij,rkj->rik", a.astype(jnp.bfloat16),
+                       b.astype(jnp.bfloat16),
+                       preferred_element_type=jnp.float32)
+    return words.astype(jnp.int32).astype(jnp.uint16)
 
 
-def make_and_kernels(coverage: Callable) -> Dict[str, Callable]:
-    """Bind the AND row kernels to a run-coverage lift implementation.
+# the array lifts ``lift_rows`` takes, batched over rows: u16[R, 32, 128],
+# i32[R] -> u16[R, 32, 128]. The same bytes out; the scatter is the cheap
+# one on the CPU, the products on the TPU, whose scatters cost tens of
+# microseconds per row
+ARRAY_LIFTS = {
+    "scatter": jax.vmap(array_coverage_by_scatter),
+    "matmul": array_coverage_by_matmul,
+}
+
+
+def on_tpu() -> bool:
+    """Does this process run on a TPU? The one device test of the kernel
+    layer: the backend ``ops`` auto-selects and the array lift below."""
+    return jax.default_backend() == "tpu"
+
+
+def device_array_lift() -> str:
+    """The array lift for the default device: ``"matmul"`` on a TPU,
+    ``"scatter"`` elsewhere."""
+    return "matmul" if on_tpu() else "scatter"
+
+
+# rows lifted per step of the chunked lift: bounds the lift temporaries
+# (one-hot operands, run scatter rows) when a whole posting stack is lifted
+_LIFT_CHUNK_ROWS = 256
+
+
+def to_words(rows):
+    """Bitmap-domain rows u16[..., 4096] -> u32[..., 16, 128]: the same row
+    as 32-bit words, the vector unit's lane width and the widest integer
+    popcount the TPU lowers. Word ``(s, l)`` holds u16 ``(s, l)`` in its low
+    half and u16 ``(s + 16, l)`` in its high half (rows of the ``32 x 128``
+    tile), so the conversion moves no data across lanes. Word ops and
+    popcounts are the same in either view."""
+    halves = rows.reshape(*rows.shape[:-1], 2, *WORD_SHAPE).astype(
+        jnp.uint32)
+    return halves[..., 0, :, :] | (halves[..., 1, :, :] << 16)
+
+
+def from_words(words):
+    """Inverse of ``to_words``: u32[..., 16, 128] -> u16[..., 4096]."""
+    halves = jnp.stack([words & 0xFFFF, words >> 16], axis=-3)
+    return halves.astype(jnp.uint16).reshape(*words.shape[:-2], ROW_WORDS)
+
+
+def _lift_flat(rows, kind, card, nruns, array_lift):
+    """``lift_rows`` over flat ``[R, 32, 128]`` rows: bitmap rows pass
+    through, empty rows become zero, and each of the array / run lifts runs
+    only when the chunk holds a row of that kind."""
+    bits = jnp.where((kind == KIND_BITMAP)[:, None, None], rows,
+                     jnp.zeros_like(rows))
+    lifts = ((KIND_ARRAY, lambda: ARRAY_LIFTS[array_lift](rows, card)),
+             (KIND_RUN, lambda: jax.vmap(coverage_by_scatter)(rows, nruns)))
+    for k, lift in lifts:
+        pred = kind == k
+
+        def run(b, pred=pred, lift=lift):
+            return jnp.where(pred[:, None, None], lift(), b)
+
+        bits = jax.lax.cond(jnp.any(pred), run, lambda b: b, bits)
+    return to_words(bits.reshape(-1, ROW_WORDS))
+
+
+def lift_rows(rows, kind, card, nruns, array_lift: str):
+    """Raw container rows -> their membership bitmaps as 32-bit words,
+    whatever their kind.
+
+    rows: u16[..., 4096]; kind/card/nruns: i32[...] (the same leading
+    shape); array_lift: a key of ``ARRAY_LIFTS``. Returns u32[..., 16, 128]
+    bitmap-domain words (``to_words``; empty rows are all zero). This is
+    the leaf load of every word-op evaluation: once each operand is in
+    bitmap domain, AND / OR / ANDNOT and the cardinality are word ops and
+    popcounts, which is all the Pallas kernel does. Array rows are lifted
+    by ``array_lift``, run rows by scatter, in chunks of rows so the
+    temporaries stay bounded for a stack of any height.
+    """
+    lead = kind.shape
+    R = kind.size
+    flat = (rows.reshape(R, *ROW_SHAPE), kind.reshape(R), card.reshape(R),
+            nruns.reshape(R))
+    if R <= _LIFT_CHUNK_ROWS:
+        return _lift_flat(*flat, array_lift).reshape(*lead, *WORD_SHAPE)
+    g = max(d for d in range(1, _LIFT_CHUNK_ROWS + 1) if R % d == 0)
+    pad = 0 if 4 * g > _LIFT_CHUNK_ROWS else (-R) % _LIFT_CHUNK_ROWS
+    if pad:          # few divisors: pad with empty rows to whole chunks
+        g = _LIFT_CHUNK_ROWS
+        flat = tuple(jnp.concatenate([x, jnp.zeros((pad,) + x.shape[1:],
+                                                   x.dtype)])
+                     for x in flat)
+    out = jax.lax.map(lambda xs: _lift_flat(*xs, array_lift),
+                      tuple(x.reshape(-1, g, *x.shape[1:]) for x in flat))
+    return out.reshape(R + pad, *WORD_SHAPE)[:R].reshape(*lead, *WORD_SHAPE)
+
+
+def make_and_kernels() -> Dict[str, Callable]:
+    """The AND row kernels of the XLA reference, one per registry cell.
 
     Every kernel: ``fn(x, y, cx, cy, rx, ry) -> (hits u16[32,128], card)``
     where ``x``/``y`` are the (possibly swapped — see ``PairClass.swap``)
@@ -308,8 +360,10 @@ def make_and_kernels(coverage: Callable) -> Dict[str, Callable]:
         # sorted prefix. 13 steps: lower_bound over a window of up to 4096
         # needs ceil(log2(4096)) + 1 halvings.
         a = x.astype(jnp.int32)
-        lo = jnp.zeros(ROW_SHAPE, jnp.int32)
-        hi = jnp.full(ROW_SHAPE, cy, jnp.int32)
+        # carries derived from both operands, so they vary with them
+        # under shard_map
+        lo = jnp.zeros_like(a + cy)
+        hi = lo + cy
 
         def body(_, lohi):
             lo, hi = lohi
@@ -346,11 +400,12 @@ def make_and_kernels(coverage: Callable) -> Dict[str, Callable]:
 
     def k_run_mask(x, y, cx, cy, rx, ry):
         # range-mask: lift x's runs to coverage words, AND with y's bitmap
-        res = jnp.bitwise_and(coverage(x, rx), y)
+        res = jnp.bitwise_and(coverage_by_scatter(x, rx), y)
         return res, jnp.sum(jax.lax.population_count(res).astype(jnp.int32))
 
     def k_run_cov_and(x, y, cx, cy, rx, ry):
-        res = jnp.bitwise_and(coverage(x, rx), coverage(y, ry))
+        res = jnp.bitwise_and(coverage_by_scatter(x, rx),
+                              coverage_by_scatter(y, ry))
         return res, jnp.sum(jax.lax.population_count(res).astype(jnp.int32))
 
     return {

@@ -12,26 +12,22 @@ Pallas launch whose grid body evaluates every node for one container column:
     slot pressure is the tree's operand depth, not its width). Plans are
     hash-consed per structural tree (``functools.lru_cache``), so the jitted
     evaluator retraces once per expression *shape*, never per query.
-  * **load**: each distinct leaf row is streamed from HBM exactly once and
-    lifted to its membership bitmap in VMEM scratch via the kind-dispatched
-    lift table (``dispatch.make_lift_kernels``) — arrays and runs
-    binary-search gather-only on the Pallas side, scatter on the XLA side;
-    both bit-identical.
-  * **ops**: every interior node is a pure 8 kB word op between scratch
-    slots — intermediates never leave VMEM.
-  * **root**: the root slot's popcount is fused into the same pass; the
-    single best-of-three canonicalization happens once, outside, in
+  * **load**: each distinct leaf's rows are lifted to their membership
+    bitmaps in XLA (``dispatch.lift_rows``: bitmap rows pass through,
+    array rows by one-hot matrix products, run rows by scatter), then
+    streamed into the kernel once.
+  * **ops**: the kernel (``word_tape_pallas``) replays the tape over the
+    rows' u32 words, a block of container rows per grid step — every
+    interior node is a word op and intermediates never leave VMEM.
+  * **root**: the root's popcount is fused into the same pass; the single
+    best-of-three canonicalization happens once, outside, in
     ``jax_roaring._finalize_rows`` (same final pass as the per-op path, so
     results stay byte-identical to ``py_roaring``).
 
-Columns where *every* leaf is empty skip their payload DMA entirely: the
-meta block carries a per-column live flag and the operand index_map redirects
-dead columns to block 0 (already resident — the same zero-cost-skip
-mechanism ``kernel.py`` uses for empty pairs).
-
-``fused_eval_ref`` is the XLA mirror (same tape, batched cond-guarded lifts,
-same word ops) — the third backend of the bit-identity contract and the
-fallback rung the ``index`` degradation ladder lands on.
+``fused_eval_ref`` is the XLA mirror (the same tape, word ops over whole
+slot arrays; array rows lifted by scatter, so the two paths share no array
+lift) — the reference of the bit-identity contract. The same
+kernel serves the pairwise entries in ``kernel.py`` with one-op tapes.
 """
 
 from __future__ import annotations
@@ -43,7 +39,6 @@ from typing import Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch as D
 
@@ -52,7 +47,7 @@ ROW_SHAPE = D.ROW_SHAPE
 
 __all__ = [
     "FusedPlan", "plan_tape", "plan_cache_size", "plan_stats",
-    "fused_eval_pallas", "fused_eval_ref",
+    "word_tape_pallas", "fused_eval_pallas", "fused_eval_ref",
     "LIFT_META_FIELDS", "pack_lift_meta",
 ]
 
@@ -63,6 +58,7 @@ _WORD_OPS = {
     "and": jnp.bitwise_and,
     "or": jnp.bitwise_or,
     "andnot": lambda a, b: jnp.bitwise_and(a, ~b),
+    "xor": jnp.bitwise_xor,
 }
 
 
@@ -163,70 +159,108 @@ LIFT_META_FIELDS = 3  # (kind, card, n_runs) per (operand, column)
 
 
 def pack_lift_meta(kind, card, nruns):
-    """Pack per-operand row tags + the per-column live flags into the fused
-    kernel's scalar-prefetch block.
+    """Pack per-operand row tags into the fused evaluator's meta block.
 
     kind/card/nruns: i32[N, C]. Layout: interleaved (kind, card, n_runs) at
-    flat index ``3 * (n * C + i)``, followed by C live flags (column ``i``
-    is live iff any operand's row there is non-empty) that the operand
-    index_map reads to skip dead columns' DMA.
+    flat index ``3 * (n * C + i)`` (i32[3*N*C]).
     """
-    fields = jnp.stack([kind, card, nruns], axis=2).reshape(-1)
-    live = jnp.any(kind != D.KIND_EMPTY, axis=0)
-    return jnp.concatenate([fields, live.astype(jnp.int32)]).astype(jnp.int32)
+    return jnp.stack([kind, card, nruns], axis=2).reshape(-1).astype(
+        jnp.int32)
+
+
+def _lift_leaves(ops_data, meta, array_lift):
+    """Every operand's rows as bitmap-domain words, u32[N, C, 16, 128]
+    (``dispatch.lift_rows``; array / run lifts skipped for leaves that hold
+    none)."""
+    N, C = ops_data.shape[0], ops_data.shape[1]
+    fields = meta.reshape(N, C, LIFT_META_FIELDS)
+    return D.lift_rows(ops_data, fields[..., 0], fields[..., 1],
+                       fields[..., 2], array_lift)
 
 
 # =============================================================================
-# Pallas fused evaluator
+# the word-op tape kernel (Pallas)
 # =============================================================================
 
-_PL_LIFTS = D.make_lift_kernels(D.coverage_by_search,
-                                D.array_coverage_by_search)
-_REF_LIFTS = D.make_lift_kernels(D.coverage_by_scatter,
-                                 D.array_coverage_by_scatter)
+WORD_SHAPE = D.WORD_SHAPE       # u32[16,128] == one 8 kB container row
+_STEP_BLOCK_BYTES = 8 << 20     # operand + output blocks of one grid step,
+                                # double-buffered, inside the scoped VMEM
 
 
-def _fused_kernel(meta_ref, ops_ref, out_ref, card_ref, scratch_ref, *,
-                  plan: FusedPlan, N: int, C: int):
-    """One container column per grid step: replay the whole tape in VMEM.
+def _rows_per_step(C: int, n_blocks: int) -> int:
+    """Container rows per grid step: the largest divisor of ``C`` (at most
+    8) whose ``n_blocks`` double-buffered row blocks fit the step budget."""
+    row = 2 * ROW_WORDS
+    for tb in range(min(8, C), 1, -1):
+        if C % tb == 0 and 2 * n_blocks * tb * row <= _STEP_BLOCK_BYTES:
+            return tb
+    return 1
 
-    ``ops_ref`` is the (N, 1, 32, 128) column block — every operand's row
-    for this column, streamed in once. ``scratch_ref`` holds the slot stack;
-    no intermediate ever returns to HBM. Dead columns (live flag 0) write
-    zeros without touching operand data — their blocks were redirected to
-    column 0 by the index_map, so the DMA is a no-op re-fetch of a resident
-    block.
+
+def _tape_kernel(*refs, plan: FusedPlan, leaves: Tuple[Tuple[int, int], ...],
+                 n_in: int):
+    """One block of container rows per grid step: replay the tape over the
+    operands' u32 words, write the root words and per-lane popcounts.
+    Intermediates are values of the step and never return to HBM.
+    ``leaves[n]`` is the (input, leaf) a tape load of operand ``n`` reads."""
+    ins, bits_ref, card_ref = refs[:n_in], refs[n_in], refs[n_in + 1]
+    slots = {}
+    for step in plan.tape:
+        if step[0] == "load":
+            _, n, dst = step
+            k, l = leaves[n]
+            slots[dst] = ins[k][0, l]
+        else:
+            op, sa, sb, dst = step
+            slots[dst] = _WORD_OPS[op](slots[sa], slots[sb])
+    res = slots[0]
+    bits_ref[0] = res
+    # per-lane partial cards (a lane-dense block); the caller sums lanes
+    card_ref[0] = jnp.sum(jax.lax.population_count(res).astype(jnp.int32),
+                          axis=1, keepdims=True)
+
+
+def word_tape_pallas(operands, plan: FusedPlan, *, interpret: bool = True):
+    """Replay ``plan``'s tape over bitmap-domain words in ONE launch.
+
+    operands: a sequence of u32[M_k, L_k, C, 16, 128] words
+    (``dispatch.to_words``) — the tape's operand ``n`` is the ``n``-th leaf
+    counting through every input's ``L_k`` leaves in order. ``M_k`` is
+    either ``M`` or 1 (one set of leaves shared by all M, read per column
+    block without being broadcast in HBM). Returns (words u32[M, C, 16,
+    128], card i32[M, C]). Grid ``(M, C/tb)`` with ``tb`` rows per step; the
+    kernel holds no scalar state.
     """
-    i = pl.program_id(0)
-    live = meta_ref[LIFT_META_FIELDS * N * C + i] != 0
+    M = max(o.shape[0] for o in operands)
+    C = operands[0].shape[2]
+    tb = _rows_per_step(C, sum(o.shape[1] for o in operands) + 1)
+    leaves = tuple((k, l) for k, o in enumerate(operands)
+                   for l in range(o.shape[1]))
 
-    @pl.when(live)
-    def _run():
-        for step in plan.tape:
-            if step[0] == "load":
-                _, n, dst = step
-                base = LIFT_META_FIELDS * (n * C) + LIFT_META_FIELDS * i
-                kind = meta_ref[base]
-                card = meta_ref[base + 1]
-                nruns = meta_ref[base + 2]
-                for k, lift in _PL_LIFTS.items():
+    def spec(L, shared):
+        block = (1, L, tb, *WORD_SHAPE)
+        if shared:
+            return pl.BlockSpec(block, lambda m, j: (0, 0, j, 0, 0))
+        return pl.BlockSpec(block, lambda m, j: (m, 0, j, 0, 0))
 
-                    @pl.when(kind == k)
-                    def _load(lift=lift, dst=dst, n=n, card=card,
-                              nruns=nruns):
-                        scratch_ref[dst] = lift(ops_ref[n, 0], card, nruns)
-            else:
-                op, sa, sb, dst = step
-                scratch_ref[dst] = _WORD_OPS[op](scratch_ref[sa],
-                                                 scratch_ref[sb])
-        res = scratch_ref[0]
-        out_ref[0] = res
-        card_ref[0] = jnp.sum(jax.lax.population_count(res).astype(jnp.int32))
-
-    @pl.when(jnp.logical_not(live))
-    def _skip():
-        out_ref[0] = jnp.zeros(ROW_SHAPE, jnp.uint16)
-        card_ref[0] = 0
+    # under shard_map the outputs vary over the mesh axes the inputs do
+    vma = frozenset().union(*(jax.typeof(o).vma for o in operands))
+    out = functools.partial(jax.ShapeDtypeStruct, vma=vma) if vma else \
+        jax.ShapeDtypeStruct
+    words, lanes = pl.pallas_call(
+        functools.partial(_tape_kernel, plan=plan, leaves=leaves,
+                          n_in=len(operands)),
+        grid=(M, C // tb),
+        in_specs=[spec(o.shape[1], o.shape[0] != M) for o in operands],
+        out_specs=[
+            pl.BlockSpec((1, tb, *WORD_SHAPE), lambda m, j: (m, j, 0, 0)),
+            pl.BlockSpec((1, tb, 1, 128), lambda m, j: (m, j, 0, 0)),
+        ],
+        out_shape=[out((M, C, *WORD_SHAPE), jnp.uint32),
+                   out((M, C, 1, 128), jnp.int32)],
+        interpret=interpret,
+    )(*operands)
+    return words, jnp.sum(lanes[:, :, 0, :], axis=-1)
 
 
 def fused_eval_pallas(ops_data: jax.Array, meta: jax.Array, *,
@@ -234,80 +268,37 @@ def fused_eval_pallas(ops_data: jax.Array, meta: jax.Array, *,
     """Evaluate a whole Boolean tree in ONE Pallas launch.
 
     ops_data: u16[N, C, 4096] raw container rows (N distinct operands, key
-    aligned). meta: the ``pack_lift_meta`` block (i32[3*N*C + C]). plan: the
-    compiled tape (static). Returns (bits u16[C, 4096] bitmap-domain root
-    rows, card i32[C]).
+    aligned). meta: the ``pack_lift_meta`` block (i32[3*N*C]). plan: the
+    compiled tape (static). Leaves are lifted to bitmap domain in XLA
+    (``dispatch.lift_rows``, array rows by one-hot products on the MXU),
+    then the tape runs in the kernel. Returns
+    (bits u16[C, 4096] bitmap-domain root rows, card i32[C]).
     """
-    N, C = ops_data.shape[0], ops_data.shape[1]
-    nc = LIFT_META_FIELDS * N * C
-
-    def ops_map(i, m):
-        # dead columns re-fetch block 0 (resident): zero-cost DMA skip
-        return (0, jnp.where(m[nc + i] != 0, i, 0), 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(C,),
-        in_specs=[pl.BlockSpec((N, 1, *ROW_SHAPE), ops_map)],
-        out_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), lambda i, m: (i, 0, 0)),
-            pl.BlockSpec((1,), lambda i, m: (i,), memory_space=pltpu.SMEM),
-        ],
-        scratch_shapes=[pltpu.VMEM((plan.n_slots, *ROW_SHAPE), jnp.uint16)],
-    )
-    bits, card = pl.pallas_call(
-        functools.partial(_fused_kernel, plan=plan, N=N, C=C),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((C, *ROW_SHAPE), jnp.uint16),
-            jax.ShapeDtypeStruct((C,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(meta, ops_data.reshape(N, C, *ROW_SHAPE))
-    return bits.reshape(C, ROW_WORDS), card
+    words, card = word_tape_pallas(
+        [_lift_leaves(ops_data, meta, "matmul")[None]], plan,
+        interpret=interpret)
+    return D.from_words(words[0]), card[0]
 
 
 # =============================================================================
-# XLA mirror (same tape, batched lifts)
+# XLA mirror (same lifts, same tape)
 # =============================================================================
 
 def fused_eval_ref(ops_data: jax.Array, meta: jax.Array, *,
                    plan: FusedPlan):
-    """XLA mirror of ``fused_eval_pallas``: identical tape, one batched
-    cond-guarded lift pass per load (scatter-based coverage), the same word
-    ops over whole [C, 32, 128] slot arrays. Bit-identical output."""
-    N, C = ops_data.shape[0], ops_data.shape[1]
-    fields = meta[:LIFT_META_FIELDS * N * C].reshape(N, C, LIFT_META_FIELDS)
-    kind, card, nruns = fields[..., 0], fields[..., 1], fields[..., 2]
-    live = meta[LIFT_META_FIELDS * N * C:] != 0
-    rows = ops_data.reshape(N, C, *ROW_SHAPE)
-
-    def load(n):
-        bits = jnp.zeros((C, *ROW_SHAPE), jnp.uint16)
-        # bitmap rows pass through; array / run rows lift via scatter only
-        # when the class is present (cond-skipped wholesale otherwise)
-        bits = jnp.where((kind[n] == D.KIND_BITMAP)[:, None, None],
-                         rows[n], bits)
-        for k in (D.KIND_ARRAY, D.KIND_RUN):
-            pred = kind[n] == k
-            lift = _REF_LIFTS[k]
-
-            def run(b, n=n, pred=pred, lift=lift):
-                lifted = jax.vmap(lift)(rows[n], card[n], nruns[n])
-                return jnp.where(pred[:, None, None], lifted, b)
-
-            bits = jax.lax.cond(jnp.any(pred), run, lambda b: b, bits)
-        return bits
-
+    """XLA mirror of ``fused_eval_pallas``: leaves lifted with the scatter
+    array lift, and the same word ops over whole [C, 16, 128] slot arrays.
+    Bit-identical output."""
+    leaves = _lift_leaves(ops_data, meta, "scatter")
     slots = {}
     for step in plan.tape:
         if step[0] == "load":
             _, n, dst = step
-            slots[dst] = load(n)
+            slots[dst] = leaves[n]
         else:
             op, sa, sb, dst = step
             slots[dst] = _WORD_OPS[op](slots[sa], slots[sb])
-    res = slots[0] * live[:, None, None].astype(jnp.uint16)
-    card_out = jnp.sum(jax.lax.population_count(res).astype(jnp.int32),
-                       axis=(1, 2))
-    return res.reshape(C, ROW_WORDS), card_out
+    res = slots[0]
+    card = jnp.sum(jax.lax.population_count(res).astype(jnp.int32),
+                   axis=(1, 2))
+    return D.from_words(res), card

@@ -1,118 +1,42 @@
-"""Pallas TPU kernels for Roaring container operations.
+"""The Pallas path of the pairwise container entries.
 
-Three kernels:
+Every entry here has the same shape on the chip: the operands' rows are
+lifted to bitmap domain in XLA (``dispatch.lift_rows``: bitmap rows pass
+through, array rows by one-hot products on the MXU, run rows by scatter),
+ONE launch of the word-op kernel (``fused.word_tape_pallas``) runs the op
+and the fused popcount over the rows' u32 words (``dispatch.to_words``), and the output semantics of the
+dispatch registry are applied on the result:
 
-1. ``container_op``: the fused word-op + popcount of Algorithms 1/3. One grid
-   step processes one 8 kB container-row pair, reshaped u16[32, 128] to match
-   the VPU lane layout (last dim 128). The cardinality is accumulated in the
-   same VMEM pass as the bitwise op — the TPU analogue of the paper's
-   "popcount rides the superscalar pipeline alongside the OR" observation
-   (S4, factors 1-3). Container-type tags arrive via scalar prefetch; fully
-   empty pairs skip the VPU work with ``@pl.when`` *and* their payload DMA:
-   the operand index_maps (``skip_dead_rows``) read the prefetched tags and
-   redirect dead columns to block 0, which stays resident — so an empty
-   column costs neither compute nor bandwidth (previously the copy still
-   ran; see DESIGN.md).
+1. ``container_op``: the fused word-op + popcount of Algorithms 1/3 over
+   bitmap-domain rows; pairs with both sides empty come out empty.
 
-2. ``array_intersect``: the galloping adaptation. Each lane binary-searches
-   the other container's packed sorted array in 13 steps (lower_bound over a
-   window of up to 4096 elements), so comparison count per lane matches
-   galloping's log bound while the VPU amortizes it across 4096 lanes.
+2. ``intersect_dispatch`` (and its stacked form): the hybrid per-type AND
+   (paper S4, extended to the 2016 follow-up's run containers). The
+   cardinality is the popcount of the lifted AND, exact for every pair
+   class; ``hits`` follows ``dispatch.AND_TABLE`` — the AND words for the
+   ``bits`` classes, and for the ``mask_a`` / ``mask_b`` classes a 0/1 mask
+   over the array side's packed slots, probed from the AND words. The XLA
+   reference (``ref.intersect_dispatch_ref``) runs the registry's row
+   kernels instead and returns the same bytes.
 
-3. ``intersect_dispatch``: the hybrid per-type dispatch (paper S4, extended
-   to the 2016 follow-up's run containers), fused. The kernel body is
-   *generated from the declarative registry* (``dispatch.AND_TABLE``): one
-   grid step reads the ``(kind, card, n_runs)`` tags from scalar prefetch and
-   ``@pl.when``-branches into exactly one registry row kernel — vectorized
-   galloping (array x array), bit probes (array x bitmap), word-AND + fused
-   popcount (bitmap x bitmap), gallop-in-ranges (array x run), and the
-   range-mask coverage forms (run x bitmap, run x run) whose run lift is the
-   gather-only binary search (``dispatch.coverage_by_search``; Pallas cannot
-   scatter). Work is *skipped*, not masked: a sparse pair never touches the
-   2^16-bit domain. This is the kernel behind ``jax_roaring.slab_and``; the
-   XLA mirror (same table, scatter-based run lift) lives in
-   ``ref.intersect_dispatch_ref``.
+3. ``array_intersect``: the array x array cell of the same path.
 
-Block shapes: container rows are (32, 128) u16 tiles = 8 kB — one row per
-grid step keeps VMEM usage at ~3 tiles (a, b, out) plus scalars, far under
-the ~16 MB VMEM budget, and the 128-wide minor dim is MXU/VPU aligned.
+A container row is 8 kB: u16[4096] in the slab layout, u32[16, 128] inside
+the kernel (two vector registers of 8 x 128 lanes).
 """
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch as D
+from . import fused as F
 
-ROW_WORDS = 4096
-ROW_SHAPE = (32, 128)          # u16[32,128] == one 8 kB container row
-KIND_EMPTY = D.KIND_EMPTY
-KIND_ARRAY = D.KIND_ARRAY
-KIND_BITMAP = D.KIND_BITMAP
-KIND_RUN = D.KIND_RUN
+ROW_WORDS = D.ROW_WORDS
+ROW_SHAPE = D.ROW_SHAPE
 
-_OPS = {
-    "and": jnp.bitwise_and,
-    "or": jnp.bitwise_or,
-    "xor": jnp.bitwise_xor,
-    "andnot": lambda a, b: jnp.bitwise_and(a, ~b),
-}
-
-
-def skip_dead_rows(live):
-    """Operand index_map factory for the zero-cost empty-column DMA skip.
-
-    ``live(scalars, i)`` decides from the scalar-prefetch block whether grid
-    column ``i`` has work; dead columns get their operand block redirected
-    to column 0, which is already resident after the first fetch — so the
-    per-column payload copy the ``@pl.when`` skip used to leave running
-    becomes a no-op re-fetch. Safe because every kernel body writes its
-    dead-column outputs without reading operand data (scalar-prefetch index
-    maps may read the scalar block; see ``PrefetchScalarGridSpec``).
-    """
-    def index_map(i, scalars):
-        return (jnp.where(live(scalars, i), i, 0), 0, 0)
-
-    return index_map
-
-
-def _pair_live(kinds, i):
-    """Either side non-empty (interleaved i32[2C] kind tags)."""
-    return jnp.logical_or(kinds[2 * i] != KIND_EMPTY,
-                          kinds[2 * i + 1] != KIND_EMPTY)
-
-
-def _pair_both_live(meta, i):
-    """Both sides non-empty (i32[6C] dispatch meta) — an AND with an empty
-    side is empty, so either-empty columns take the dead branch."""
-    return jnp.logical_and(meta[D.META_FIELDS * i] != KIND_EMPTY,
-                           meta[D.META_FIELDS * i + 1] != KIND_EMPTY)
-
-
-def _container_op_kernel(kinds_ref, a_ref, b_ref, out_ref, card_ref, *, op: str):
-    """One container-row pair per grid step; fused op + popcount."""
-    i = pl.program_id(0)
-    ka = kinds_ref[2 * i]
-    kb = kinds_ref[2 * i + 1]
-    both_empty = jnp.logical_and(ka == KIND_EMPTY, kb == KIND_EMPTY)
-
-    @pl.when(jnp.logical_not(both_empty))
-    def _compute():
-        res = _OPS[op](a_ref[0], b_ref[0])
-        out_ref[0] = res
-        # Alg. 1 line 7 / Alg. 3 line 5: popcount fused into the same pass
-        card_ref[0] = jnp.sum(
-            jax.lax.population_count(res).astype(jnp.int32))
-
-    @pl.when(both_empty)
-    def _skip():
-        out_ref[0] = jnp.zeros(ROW_SHAPE, jnp.uint16)
-        card_ref[0] = 0
+_PROBE = D.make_and_kernels()["probe"]
 
 
 def container_op_pallas(a_bits: jax.Array, b_bits: jax.Array,
@@ -124,61 +48,72 @@ def container_op_pallas(a_bits: jax.Array, b_bits: jax.Array,
     kinds: i32[2C] interleaved (kind_a0, kind_b0, kind_a1, ...) tags.
     Returns (out_bits u16[C, 4096], card i32[C]).
     """
-    C = a_bits.shape[0]
-    a3 = a_bits.reshape(C, *ROW_SHAPE)
-    b3 = b_bits.reshape(C, *ROW_SHAPE)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), skip_dead_rows(_pair_live)),
-            pl.BlockSpec((1, *ROW_SHAPE), skip_dead_rows(_pair_live)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), lambda i, k: (i, 0, 0)),
-            pl.BlockSpec((1,), lambda i, k: (i,), memory_space=pltpu.SMEM),
-        ],
-    )
-    out, card = pl.pallas_call(
-        functools.partial(_container_op_kernel, op=op),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((C, *ROW_SHAPE), jnp.uint16),
-            jax.ShapeDtypeStruct((C,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(kinds, a3, b3)
-    return out.reshape(C, ROW_WORDS), card
+    live = (kinds[0::2] != D.KIND_EMPTY) | (kinds[1::2] != D.KIND_EMPTY)
+    words, card = F.word_tape_pallas(
+        [D.to_words(a_bits)[None, None], D.to_words(b_bits)[None, None]],
+        F.plan_tape((op, 0, 1)), interpret=interpret)
+    return (jnp.where(live[:, None], D.from_words(words[0]), jnp.uint16(0)),
+            jnp.where(live, card[0], 0))
 
 
-def _array_intersect_kernel(cards_ref, a_ref, b_ref, hit_ref, count_ref):
-    """Vectorized binary search: every element of A (4096 lanes) searches the
-    packed sorted array B in 13 halving steps (lower_bound over a window of
-    up to 4096 needs ceil(log2(4096)) + 1) — galloping's log bound, SIMD."""
-    i = pl.program_id(0)
-    card_b = cards_ref[2 * i + 1]
-    a = a_ref[0].astype(jnp.int32)                # (32,128) values (0xFFFF pad)
-    b = b_ref[0].reshape(ROW_WORDS).astype(jnp.int32)
+def _mask_classes(bits, a_rows, b_rows, ka, kb, ca, cb):
+    """Apply the registry's output semantic per row: ``mask_a`` / ``mask_b``
+    rows become the 0/1 slot mask of the array side, probed from the AND
+    words; ``bits`` rows keep the words."""
+    ma = D.out_mask("mask_a", ka, kb)
+    mb = D.out_mask("mask_b", ka, kb)
 
-    lo = jnp.zeros(ROW_SHAPE, jnp.int32)
-    hi = jnp.full(ROW_SHAPE, card_b, jnp.int32)   # search window [lo, hi)
+    def one(w, a, b, ma_i, mb_i, ca_i, cb_i):
+        arr = jnp.where(ma_i, a, b).reshape(ROW_SHAPE)
+        mask, _ = _PROBE(arr, w.reshape(ROW_SHAPE),
+                         jnp.where(ma_i, ca_i, cb_i), 0, 0, 0)
+        return jnp.where(ma_i | mb_i, mask.reshape(ROW_WORDS), w)
 
-    def body(_, lohi):
-        lo, hi = lohi
-        mid = (lo + hi) // 2
-        vals = jnp.take(b, jnp.clip(mid, 0, ROW_WORDS - 1))
-        go_right = vals < a
-        return (jnp.where(go_right, mid + 1, lo), jnp.where(go_right, hi, mid))
+    def run(w):
+        return jax.vmap(jax.vmap(one))(w, a_rows, b_rows, ma, mb, ca, cb)
 
-    lo, hi = jax.lax.fori_loop(0, 13, body, (lo, hi))
-    found = jnp.take(b, jnp.clip(lo, 0, ROW_WORDS - 1)) == a
-    found = jnp.logical_and(found, lo < card_b)
-    card_a = cards_ref[2 * i]
-    flat_pos = (jax.lax.broadcasted_iota(jnp.int32, ROW_SHAPE, 0) * 128
-                + jax.lax.broadcasted_iota(jnp.int32, ROW_SHAPE, 1))
-    found = jnp.logical_and(found, flat_pos < card_a)
-    hit_ref[0] = found.astype(jnp.uint16)
-    count_ref[0] = jnp.sum(found.astype(jnp.int32))
+    return jax.lax.cond(jnp.any(ma | mb), run, lambda w: w, bits)
+
+
+def intersect_dispatch_stacked_pallas(a_data: jax.Array, b_data: jax.Array,
+                                      meta: jax.Array, interpret: bool = True):
+    """Kind-dispatch AND of N key-aligned slab pairs in one launch.
+
+    a_data: u16[N, C, 4096] raw container rows (packed arrays, bitmap words
+    or run pairs, per their kind tag). b_data: the same shape, or u16[C,
+    4096] — one slab shared by all N (a query scored against a stack),
+    which is lifted once and never broadcast in HBM. meta: i32[N, 6C]
+    per-slab interleaved (kind_a, kind_b, card_a, card_b, nruns_a,
+    nruns_b). Returns (hits u16[N, C, 4096], card i32[N, C]).
+    """
+    N, C = a_data.shape[0], a_data.shape[1]
+    ka, kb, ca, cb, ra, rb = (f.reshape(N, C)
+                              for f in D.unpack_meta(meta.reshape(-1)))
+    la = D.lift_rows(a_data, ka, ca, ra, "matmul")
+    if b_data.ndim == 2:
+        lb = D.lift_rows(b_data[None], kb[:1], cb[:1], rb[:1], "matmul")
+        b_data = jnp.broadcast_to(b_data, a_data.shape)
+    else:
+        lb = D.lift_rows(b_data, kb, cb, rb, "matmul")
+    words, card = F.word_tape_pallas([la[:, None], lb[:, None]],
+                                     F.plan_tape(("and", 0, 1)),
+                                     interpret=interpret)
+    return _mask_classes(D.from_words(words), a_data, b_data, ka, kb, ca,
+                         cb), card
+
+
+def intersect_dispatch_pallas(a_data: jax.Array, b_data: jax.Array,
+                              meta: jax.Array, interpret: bool = True):
+    """Kind-dispatch AND over key-aligned container rows.
+
+    a_data, b_data: u16[C, 4096] raw container rows. meta: i32[6C]
+    interleaved (kind_a, kind_b, card_a, card_b, nruns_a, nruns_b) per row.
+    Returns (hits u16[C, 4096], card i32[C]); see the module docstring for
+    the per-pair-class meaning of ``hits``.
+    """
+    hits, card = intersect_dispatch_stacked_pallas(
+        a_data[None], b_data[None], meta[None], interpret=interpret)
+    return hits[0], card[0]
 
 
 def array_intersect_pallas(a_arr: jax.Array, b_arr: jax.Array,
@@ -187,121 +122,11 @@ def array_intersect_pallas(a_arr: jax.Array, b_arr: jax.Array,
 
     a_arr, b_arr: u16[C, 4096]; cards: i32[2C] interleaved (card_a, card_b).
     Returns (hits u16[C, 4096] — 1 where a value of A is also in B — and
-    count i32[C]). Compaction of hits to packed form stays in XLA (scatter).
+    count i32[C]).
     """
     C = a_arr.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), lambda i, k: (i, 0, 0)),
-            pl.BlockSpec((1, *ROW_SHAPE), lambda i, k: (i, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), lambda i, k: (i, 0, 0)),
-            pl.BlockSpec((1,), lambda i, k: (i,), memory_space=pltpu.SMEM),
-        ],
-    )
-    hits, count = pl.pallas_call(
-        _array_intersect_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((C, *ROW_SHAPE), jnp.uint16),
-            jax.ShapeDtypeStruct((C,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(cards, a_arr.reshape(C, *ROW_SHAPE), b_arr.reshape(C, *ROW_SHAPE))
-    return hits.reshape(C, ROW_WORDS), count
-
-
-_PL_KERNELS = D.make_and_kernels(D.coverage_by_search)
-
-
-def _intersect_dispatch_kernel(meta_ref, a_ref, b_ref, hits_ref, card_ref):
-    """Hybrid per-kind dispatch, generated from ``dispatch.AND_TABLE``: one
-    container pair per grid step, ``@pl.when`` selects exactly one registry
-    row kernel by the pair's ``(kind_a, kind_b)`` cell.
-
-    ``meta`` is i32[6C] interleaved (kind_a, kind_b, card_a, card_b,
-    nruns_a, nruns_b). Output per row follows the cell's ``out`` semantic:
-    a 0/1 mask over the array side's 4096 slots (``mask_a``/``mask_b``), or
-    the word-op bitmap words (``bits`` — bitmap x bitmap and the
-    coverage-lifted run forms). ``card`` is exact either way (fused popcount
-    for the bits cases).
-    """
-    i = pl.program_id(0)
-    ka, kb, ca, cb, ra, rb = D.unpack_meta(meta_ref, i)
-    matched = jnp.zeros((), jnp.bool_)
-
-    for cls in D.AND_TABLE:
-        pred = D.class_predicate(cls, ka, kb)
-        matched = jnp.logical_or(matched, pred)
-
-        @pl.when(pred)
-        def _cell(cls=cls):
-            x, y, cx, cy, rx, ry = D.bind_args(cls, a_ref[0], b_ref[0],
-                                               ca, cb, ra, rb)
-            hits, card = _PL_KERNELS[cls.kernel](x, y, cx, cy, rx, ry)
-            hits_ref[0] = hits
-            card_ref[0] = card
-
-    @pl.when(jnp.logical_not(matched))
-    def _dead():
-        hits_ref[0] = jnp.zeros(ROW_SHAPE, jnp.uint16)
-        card_ref[0] = 0
-
-
-def intersect_dispatch_pallas(a_data: jax.Array, b_data: jax.Array,
-                              meta: jax.Array, interpret: bool = True):
-    """Fused hybrid intersection over key-aligned container rows.
-
-    a_data, b_data: u16[C, 4096] raw container rows (packed arrays, bitmap
-    words, or run pairs, per their kind tag — *not* lifted to bitmap domain).
-    meta: i32[6C] interleaved (kind_a, kind_b, card_a, card_b, nruns_a,
-    nruns_b) per row. Returns (hits u16[C, 4096], card i32[C]); see the
-    kernel docstring for the per-pair-class meaning of ``hits``.
-    """
-    C = a_data.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(C,),
-        in_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), skip_dead_rows(_pair_both_live)),
-            pl.BlockSpec((1, *ROW_SHAPE), skip_dead_rows(_pair_both_live)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, *ROW_SHAPE), lambda i, k: (i, 0, 0)),
-            pl.BlockSpec((1,), lambda i, k: (i,), memory_space=pltpu.SMEM),
-        ],
-    )
-    hits, card = pl.pallas_call(
-        _intersect_dispatch_kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((C, *ROW_SHAPE), jnp.uint16),
-            jax.ShapeDtypeStruct((C,), jnp.int32),
-        ],
-        interpret=interpret,
-    )(meta, a_data.reshape(C, *ROW_SHAPE), b_data.reshape(C, *ROW_SHAPE))
-    return hits.reshape(C, ROW_WORDS), card
-
-
-def intersect_dispatch_stacked_pallas(a_data: jax.Array, b_data: jax.Array,
-                                      meta: jax.Array, interpret: bool = True):
-    """Batched-meta entry point for the dispatch kernel: a whole *slab stack*
-    in one fused launch.
-
-    a_data, b_data: u16[N, C, 4096] — N key-aligned slabs of C raw container
-    rows each (the ``repro.index.SlabStack`` layout). meta: i32[N, 6C], the
-    per-slab interleaved (kind_a, kind_b, card_a, card_b, nruns_a, nruns_b)
-    scalar-prefetch block. The stack flattens to a single ``N*C`` grid — one
-    kernel launch and one scalar-prefetch transfer for the whole wide query
-    instead of N separate dispatches (vmap of the per-slab entry would also
-    fuse, but this keeps the grid explicit and the meta contiguous for SMEM).
-    Returns (hits u16[N, C, 4096], card i32[N, C]).
-    """
-    N, C = a_data.shape[0], a_data.shape[1]
-    hits, card = intersect_dispatch_pallas(
-        a_data.reshape(N * C, ROW_WORDS), b_data.reshape(N * C, ROW_WORDS),
-        meta.reshape(-1), interpret=interpret)
-    return hits.reshape(N, C, ROW_WORDS), card.reshape(N, C)
+    arrays = jnp.full((C,), D.KIND_ARRAY, jnp.int32)
+    zeros = jnp.zeros((C,), jnp.int32)
+    meta = jnp.stack([arrays, arrays, cards[0::2], cards[1::2], zeros, zeros],
+                     axis=1).reshape(-1)
+    return intersect_dispatch_pallas(a_arr, b_arr, meta, interpret=interpret)

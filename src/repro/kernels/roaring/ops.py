@@ -1,8 +1,12 @@
 """Jit'd wrappers for the roaring container kernels.
 
-``use_pallas=None`` auto-selects: the Pallas body targets TPU; on CPU (this
-container) it runs in interpret mode inside tests, while jitted production
-entry points fall back to the XLA reference formulation (same math).
+``use_pallas=None`` auto-selects the backend from the device: on a TPU every
+entry launches its Pallas path (``kernel.py`` / ``fused.py``: kind lifts in
+XLA, word ops and popcount in the Pallas kernel), elsewhere the XLA
+reference (``ref.py``, same bytes out). ``use_pallas=True`` off the TPU runs
+the Pallas kernel in interpret mode, which is how the CPU tests hold the two
+paths to each other; ``tests/test_tpu_compile.py`` compiles the Pallas
+paths for a TPU v5e.
 
 Robustness hooks (PR 6): the public entry points are thin Python wrappers
 over the jitted implementations, so two per-launch controls exist without
@@ -42,13 +46,10 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from . import dispatch as _d
 from . import fused as _f
 from . import kernel as _k
 from . import ref as _ref
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 # -- per-launch controls ------------------------------------------------------
@@ -103,7 +104,7 @@ def current_backend() -> str:
     """The backend an auto-selecting launch would use right now."""
     if _BACKEND_OVERRIDE is not None:
         return _BACKEND_OVERRIDE
-    return "pallas" if _on_tpu() else "xla"
+    return "pallas" if _d.on_tpu() else "xla"
 
 
 def set_fault_hook(hook: Optional[Callable[[str], None]]):
@@ -124,7 +125,7 @@ def _resolve(use_pallas: Optional[bool], interpret: bool,
     if use_pallas is None and not interpret:
         use_pallas = current_backend() == "pallas"
     elif use_pallas is None:
-        use_pallas = _on_tpu()
+        use_pallas = _d.on_tpu()
     backend = "pallas" if (use_pallas or interpret) else "xla"
     if _LAUNCH_HOOKS:
         ev = LaunchEvent(entry, backend)
@@ -142,7 +143,7 @@ def _resolve(use_pallas: Optional[bool], interpret: bool,
 def _container_op(a_bits, b_bits, kinds, op, use_pallas, interpret):
     if use_pallas or interpret:
         return _k.container_op_pallas(a_bits, b_bits, kinds, op,
-                                      interpret=not _on_tpu())
+                                      interpret=not _d.on_tpu())
     return _ref.container_op_ref(a_bits, b_bits, kinds, op)
 
 
@@ -157,7 +158,7 @@ def container_op(a_bits, b_bits, kinds, op: str = "or",
 def _array_intersect(a_arr, b_arr, cards, use_pallas, interpret):
     if use_pallas or interpret:
         return _k.array_intersect_pallas(a_arr, b_arr, cards,
-                                         interpret=not _on_tpu())
+                                         interpret=not _d.on_tpu())
     return _ref.array_intersect_ref(a_arr, b_arr, cards)
 
 
@@ -172,7 +173,7 @@ def array_intersect(a_arr, b_arr, cards,
 def _intersect_dispatch(a_data, b_data, meta, use_pallas, interpret):
     if use_pallas or interpret:
         return _k.intersect_dispatch_pallas(a_data, b_data, meta,
-                                            interpret=not _on_tpu())
+                                            interpret=not _d.on_tpu())
     return _ref.intersect_dispatch_ref(a_data, b_data, meta)
 
 
@@ -186,7 +187,7 @@ def intersect_dispatch(a_data, b_data, meta,
     meta: i32[6C] interleaved (kind_a, kind_b, card_a, card_b, nruns_a,
     nruns_b). Returns (hits u16[C, 4096], card i32[C]) — the slab layer
     compacts / lazily canonicalizes best-of-three on top of this. Pallas
-    (``@pl.when`` skip) on TPU, XLA reference elsewhere.
+    path on TPU, XLA reference elsewhere.
     """
     use_pallas, interpret = _resolve(use_pallas, interpret,
                                      "intersect_dispatch")
@@ -197,8 +198,9 @@ def intersect_dispatch(a_data, b_data, meta,
 def _intersect_dispatch_stacked(a_data, b_data, meta, use_pallas, interpret):
     if use_pallas or interpret:
         return _k.intersect_dispatch_stacked_pallas(a_data, b_data, meta,
-                                                    interpret=not _on_tpu())
+                                                    interpret=not _d.on_tpu())
     N, C = a_data.shape[0], a_data.shape[1]
+    b_data = jnp.broadcast_to(b_data, a_data.shape)
     hits, card = _ref.intersect_dispatch_ref(
         a_data.reshape(N * C, a_data.shape[2]),
         b_data.reshape(N * C, b_data.shape[2]), meta.reshape(-1))
@@ -210,7 +212,7 @@ def _intersect_dispatch_stacked(a_data, b_data, meta, use_pallas, interpret):
 def _fused_tree(ops_data, meta, plan, use_pallas, interpret):
     if use_pallas or interpret:
         return _f.fused_eval_pallas(ops_data, meta, plan=plan,
-                                    interpret=not _on_tpu())
+                                    interpret=not _d.on_tpu())
     return _f.fused_eval_ref(ops_data, meta, plan=plan)
 
 
@@ -219,11 +221,11 @@ def fused_tree(ops_data, meta, plan,
     """Evaluate a whole compiled Boolean expression tree in ONE launch.
 
     ops_data: u16[N, C, 4096] raw container rows (one per distinct leaf, key
-    aligned); meta: the ``fused.pack_lift_meta`` scalar-prefetch block
-    (i32[3*N*C + C]); plan: a ``fused.FusedPlan`` (static — hash-consed per
-    expression shape, so same-shape queries never retrace). Returns
+    aligned); meta: the ``fused.pack_lift_meta`` block (i32[3*N*C]); plan:
+    a ``fused.FusedPlan`` (static — hash-consed per expression shape, so
+    same-shape queries never retrace). Returns
     (bits u16[C, 4096] bitmap-domain root rows, card i32[C]); the caller
-    runs the single best-of-three canonicalization. Pallas mega-kernel on
+    runs the single best-of-three canonicalization. Pallas tape kernel on
     TPU, tape-mirroring XLA evaluator elsewhere.
     """
     use_pallas, interpret = _resolve(use_pallas, interpret, "fused_tree")
@@ -237,10 +239,11 @@ def intersect_dispatch_stacked(a_data, b_data, meta,
     slabs of C rows each in one launch — the ``repro.index`` wide-query
     engine's inner kernel.
 
-    a_data, b_data: u16[N, C, 4096] raw container rows; meta: i32[N, 6C]
-    per-slab interleaved (kind, card, n_runs) x2. Returns
-    (hits u16[N, C, 4096], card i32[N, C]) with the same per-pair-class
-    semantics as ``intersect_dispatch``.
+    a_data: u16[N, C, 4096] raw container rows; b_data: the same shape, or
+    u16[C, 4096] — one slab shared by all N (a query scored against a
+    stack); meta: i32[N, 6C] per-slab interleaved (kind, card, n_runs) x2.
+    Returns (hits u16[N, C, 4096], card i32[N, C]) with the same
+    per-pair-class semantics as ``intersect_dispatch``.
     """
     use_pallas, interpret = _resolve(use_pallas, interpret,
                                      "intersect_dispatch_stacked")

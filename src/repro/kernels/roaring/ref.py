@@ -1,13 +1,12 @@
 """Pure-jnp oracles for the roaring container kernels.
 
-``intersect_dispatch_ref`` consumes the same declarative pair-class registry
-(``dispatch.AND_TABLE``) as the Pallas kernel: one cond-guarded vmapped pass
-per grid cell, selected by the cell's ``(kind_a, kind_b)`` predicate. XLA has
-no per-row skip, so within a pass every row computes masked — but a class
-with no matching rows is skipped wholesale at runtime by ``lax.cond``, and
-none of the cheap paths touches the 2^16-element domain. The run-coverage
-lift binds to the scatter formulation (O(n_runs + 4096) per row) instead of
-the kernel's gather-only search; both are bit-identical.
+``intersect_dispatch_ref`` runs the declarative pair-class registry
+(``dispatch.AND_TABLE``): one cond-guarded vmapped pass per grid cell,
+selected by the cell's ``(kind_a, kind_b)`` predicate. XLA has no per-row
+skip, so within a pass every row computes masked — but a class with no
+matching rows is skipped wholesale at runtime by ``lax.cond``, and none of
+the cheap paths touches the 2^16-element domain. The Pallas path lifts every
+row to bitmap domain instead and returns the same bytes.
 """
 
 from __future__ import annotations
@@ -57,7 +56,7 @@ def array_intersect_ref(a_arr: jax.Array, b_arr: jax.Array, cards: jax.Array):
     return jax.vmap(one)(a_arr, b_arr, card_a, card_b)
 
 
-_KERNELS = D.make_and_kernels(D.coverage_by_scatter)
+_KERNELS = D.make_and_kernels()
 
 
 def intersect_dispatch_ref(a_data: jax.Array, b_data: jax.Array,
@@ -76,8 +75,9 @@ def intersect_dispatch_ref(a_data: jax.Array, b_data: jax.Array,
     a3 = a_data.reshape(C, *ROW_SHAPE)
     b3 = b_data.reshape(C, *ROW_SHAPE)
 
-    hits = jnp.zeros((C, *ROW_SHAPE), jnp.uint16)
-    card = jnp.zeros((C,), jnp.int32)
+    # derived from the operands, so they vary with them under shard_map
+    hits = jnp.zeros_like(a3)
+    card = jnp.zeros_like(ca)
     for cls in D.AND_TABLE:
         pred = D.class_predicate(cls, ka, kb)
         fn = _KERNELS[cls.kernel]

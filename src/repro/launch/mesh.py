@@ -14,12 +14,21 @@ from __future__ import annotations
 import jax
 
 
+def _mesh(shape, axes):
+    # Auto axes: shardings propagate through jit and gathers from a sharded
+    # array need no explicit output sharding (``jax.make_mesh`` defaults to
+    # Explicit axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_test_mesh(data: int = 2, model: int = 2):
-    """Small mesh for CPU tests (requires >= data*model host devices)."""
-    return jax.make_mesh((data, model), ("data", "model"))
+    """Small mesh: the four chips of one host as (4, 1), or host devices
+    for CPU tests (requires >= data*model devices)."""
+    return _mesh((data, model), ("data", "model"))

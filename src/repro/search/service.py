@@ -142,6 +142,15 @@ def lower(q: Query) -> Tuple[_engine.Expr, Tuple[str, ...]]:
 # the LRU hot-term slab cache
 # =============================================================================
 
+# the cache's two programs: the sharded fetch (one batched gather from the
+# possibly mesh-sharded posting stack) and the fill of the cache's slots
+fetch_rows = jax.jit(lambda sp, sc, sk, sn, rows:
+                     (sp[rows], sc[rows], sk[rows], sn[rows]))
+fill_slots = jax.jit(lambda cp, cc, ck, cn, sp, sc, sk, sn, sl:
+                     (cp.at[sl].set(sp), cc.at[sl].set(sc),
+                      ck.at[sl].set(sk), cn.at[sl].set(sn)))
+
+
 class _SlabCache:
     """Resident cache stack ``[slots, C]`` + LRU term -> slot map.
 
@@ -159,18 +168,17 @@ class _SlabCache:
         # jitted fill compiles exactly ONE shape class — a variable-width
         # fill retraces per distinct miss count (measured ~240 ms per
         # novel shape on CPU), dwarfing the O(slots) gather it avoids
-        self.payload = jnp.zeros((slots + 1, index.C, jr.ROW_WORDS),
-                                 st.payload.dtype)
-        self.cards = jnp.zeros((slots + 1, index.C), st.cards.dtype)
-        self.kinds = jnp.zeros((slots + 1, index.C), st.kinds.dtype)
-        self.nruns = jnp.zeros((slots + 1, index.C), st.nruns.dtype)
+        # the cache lives on ONE device, where the query kernels run: a
+        # Pallas kernel cannot be partitioned over a mesh
+        self.device = min(st.payload.devices(), key=lambda d: d.id)
+        self.payload, self.cards, self.kinds, self.nruns = jax.device_put(
+            (jnp.zeros((slots + 1, index.C, jr.ROW_WORDS), st.payload.dtype),
+             jnp.zeros((slots + 1, index.C), st.cards.dtype),
+             jnp.zeros((slots + 1, index.C), st.kinds.dtype),
+             jnp.zeros((slots + 1, index.C), st.nruns.dtype)), self.device)
         self.lru: "collections.OrderedDict[str, int]" = \
             collections.OrderedDict()
         self._free = list(range(slots - 1, -1, -1))
-        self._fill = jax.jit(
-            lambda cp, cc, ck, cn, sp, sc, sk, sn, rows, sl:
-            (cp.at[sl].set(sp[rows]), cc.at[sl].set(sc[rows]),
-             ck.at[sl].set(sk[rows]), cn.at[sl].set(sn[rows])))
 
     def ensure(self, terms: Sequence[str]) -> Dict[str, int]:
         """Make every term resident; return term -> cache slot. Hits are
@@ -201,15 +209,53 @@ class _SlabCache:
                                + [0] * pad, jnp.int32)
             sl = jnp.asarray(alloc + [self.slots] * pad, jnp.int32)
             # the sharded fetch: one batched gather from the (possibly
-            # mesh-sharded) posting stack into the replicated cache stack
-            self.payload, self.cards, self.kinds, self.nruns = self._fill(
-                self.payload, self.cards, self.kinds, self.nruns,
-                st.payload, st.cards, st.kinds, st.nruns, rows, sl)
+            # mesh-sharded) posting stack, moved to the cache's device
+            fetched = jax.device_put(
+                fetch_rows(st.payload, st.cards, st.kinds, st.nruns, rows),
+                self.device)
+            self.payload, self.cards, self.kinds, self.nruns = fill_slots(
+                self.payload, self.cards, self.kinds, self.nruns, *fetched,
+                sl)
         return {t: self.lru[t] for t in terms}
 
     def resident(self) -> Tuple[str, ...]:
         """Resident terms in LRU -> MRU order (test/introspection surface)."""
         return tuple(self.lru)
+
+
+def query_programs(expr: _engine.Expr, B: int, L: int, C: int,
+                   want_rows: bool):
+    """(fused, per-op) batch evaluators of one (signature, B) class, over
+    the cache arrays ``(payload, cards, kinds, nruns)`` and the batch's
+    ``i32[B, L]`` cache slots. They run where the cache lives, one device.
+
+    The batch packs as a pseudo-stack: leaf ``i``'s rows for all B requests
+    concatenate along the container axis (``[L, B*C]``; keys
+    ``tile(arange(C), B)``), so one engine evaluation covers the whole
+    batch and per-request results are the ``[B, C]`` unflattening. Returns
+    per-request counts ``i32[B]``, or with ``want_rows`` the per-row cards
+    ``i32[B, C]`` and bitmap-domain rows ``u16[B, C, 4096]``.
+    """
+    def make(ev):
+        def f(payload, cards, kinds, nruns, slots):
+            keys_flat = jnp.tile(jnp.arange(C, dtype=jnp.int32), B)
+
+            def fl(x):
+                x = jnp.swapaxes(x[slots], 0, 1)          # [L, B, C, ...]
+                return x.reshape((L, B * C) + x.shape[3:])
+            flat = roaring.RoaringSlab(
+                keys=jnp.broadcast_to(keys_flat, (L, B * C)),
+                kinds=fl(kinds), cards=fl(cards), nruns=fl(nruns),
+                payload=fl(payload), C=B * C)
+            data, card, kind = ev(flat, keys_flat, expr)
+            rc = card.reshape(B, C)
+            if not want_rows:
+                return jnp.sum(rc, axis=1)
+            words = jr._lift_rows(data, card, kind)
+            return rc, words.reshape(B, C, jr.ROW_WORDS)
+        return f
+
+    return make(_engine._fused_eval), make(_engine._eval)
 
 
 # =============================================================================
@@ -384,37 +430,10 @@ class SearchService:
     # -- execution ------------------------------------------------------------
     def _build_fns(self, expr: _engine.Expr, B: int, L: int,
                    want_rows: bool):
-        """(fused, per-op) batch evaluators for one (signature, B) class.
-
-        The batch packs as a pseudo-stack: leaf ``i``'s rows for all B
-        requests concatenate along the container axis (``[L, B*C]``; keys
-        ``tile(arange(C), B)``), so one engine evaluation covers the whole
-        batch and per-request results are the ``[B, C]`` unflattening.
-        """
-        C = self.index.C
-        keys_flat = jnp.tile(self._keys_row, B)
-
-        def make(ev):
-            def f(payload, cards, kinds, nruns, slots):
-                def fl(x):
-                    x = jnp.swapaxes(x[slots], 0, 1)      # [L, B, C, ...]
-                    return x.reshape((L, B * C) + x.shape[3:])
-                flat = roaring.RoaringSlab(
-                    keys=jnp.broadcast_to(keys_flat, (L, B * C)),
-                    kinds=fl(kinds), cards=fl(cards), nruns=fl(nruns),
-                    payload=fl(payload), C=B * C)
-                data, card, kind = ev(flat, keys_flat, expr)
-                rc = card.reshape(B, C)
-                if not want_rows:
-                    return jnp.sum(rc, axis=1)
-                words = jr._lift_rows(data, card, kind)
-                return rc, words.reshape(B, C, jr.ROW_WORDS)
-            return f
-
-        per_op, fused = make(_engine._eval), make(_engine._fused_eval)
-        if self.jit_queries:
-            per_op, fused = jax.jit(per_op), jax.jit(fused)
-        return fused, per_op
+        """(fused, per-op) batch evaluators for one (signature, B) class,
+        jitted unless ``jit_queries=False``."""
+        fns = query_programs(expr, B, L, self.index.C, want_rows)
+        return tuple(map(jax.jit, fns)) if self.jit_queries else fns
 
     def _execute(self, batch: List[_Request], slot_of: Dict[str, int]):
         B, L = len(batch), len(batch[0].terms)

@@ -12,6 +12,13 @@ import os
 
 import pytest
 
+# every program XLA compiles for the CPU keeps memory mappings of its code;
+# a worker that has compiled a few thousand programs reaches the kernel's
+# per-process limit (vm.max_map_count, 65,530 by default) and the next
+# compile segfaults. Past this many mappings the fixture below drops JAX's
+# compiled-program caches (only recompiles are lost).
+_MAPPINGS_BUDGET = 30_000
+
 
 def pytest_configure(config):
     if os.environ.get("REPRO_STRICT_DEPRECATIONS"):
@@ -19,31 +26,17 @@ def pytest_configure(config):
             "filterwarnings", r"error::DeprecationWarning:repro\.")
 
 
-def _thunk_runtime_compile_bug() -> bool:
-    """jaxlib 0.4.36's CPU thunk runtime segfaults inside backend_compile
-    once a few hundred compiled executables are live in one process (this
-    suite's compile-heavy dispatch property tests reliably hit it; every
-    test passes in isolation — only the accumulation kills the compiler).
-    The legacy runtime is no escape: it miscompiles the flash-attn softcap
-    path outright. Fixed in later jaxlib releases."""
+def _mappings() -> int:
     try:
-        import jaxlib
-        major, minor, patch = (int(x) for x in
-                               jaxlib.__version__.split(".")[:3])
-        return (major, minor, patch) <= (0, 4, 36)
-    except Exception:
-        return False
-
-
-_NEEDS_CACHE_SHED = _thunk_runtime_compile_bug()
+        with open("/proc/self/maps") as f:
+            return sum(1 for _ in f)
+    except OSError:            # no procfs: nothing to measure
+        return 0
 
 
 @pytest.fixture(autouse=True)
 def _shed_compiled_programs():
-    """On affected jaxlib versions, drop compiled executables after each
-    test so the live count stays below the thunk-runtime crash threshold.
-    Costs recompiles, so it is version-gated to the buggy runtime only."""
     yield
-    if _NEEDS_CACHE_SHED:
+    if _mappings() > _MAPPINGS_BUDGET:
         import jax
         jax.clear_caches()

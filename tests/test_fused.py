@@ -5,7 +5,7 @@ cards, kinds, serialized stream — to the per-op tree-reduce path AND to
 ``py_roaring`` set algebra, on all three backends (Pallas interpret, the
 tape-mirroring XLA evaluator, and the per-op reference it degrades to);
 plans retrace once per expression shape; the degradation ladder falls back
-from the fused rung bit-identically; and the empty-column DMA skip holds for
+from the fused rung bit-identically; and empty columns come out empty from
 the pairwise kernels and the fused kernel alike.
 """
 
@@ -267,19 +267,117 @@ def test_fused_xla_rung_failure_propagates():
     index.reset_degradation()
 
 
-# --------------------------------------------------- empty-column DMA skip
-def test_skip_dead_rows_index_map():
-    kinds = jnp.asarray([0, 0, 1, 0, 0, 2, 0, 0], jnp.int32)  # 4 pairs
-    imap = K.skip_dead_rows(K._pair_live)
-    got = [tuple(int(jnp.asarray(x)) for x in imap(i, kinds))
-           for i in range(4)]
-    assert got == [(0, 0, 0), (1, 0, 0), (2, 0, 0), (0, 0, 0)]
-    both = K.skip_dead_rows(K._pair_both_live)
-    meta = jnp.asarray([1, 0, 9, 0, 0, 0,     # a live, b empty -> dead
-                        2, 2, 9, 9, 0, 0], jnp.int32)
-    got = [tuple(int(jnp.asarray(x)) for x in both(i, meta))
-           for i in range(2)]
-    assert got == [(0, 0, 0), (1, 0, 0)]
+# ------------------------------------------------ leaf lifts and empty rows
+def _packed_rows(value_sets):
+    rows = np.full((len(value_sets), jr.ROW_WORDS), 0xFFFF, np.uint16)
+    for i, v in enumerate(value_sets):
+        rows[i, : len(v)] = v
+    return rows
+
+
+def _bitmap_rows(value_sets):
+    rows = np.zeros((len(value_sets), jr.ROW_WORDS), np.uint16)
+    for i, v in enumerate(value_sets):
+        v = np.asarray(v, np.int64)
+        np.bitwise_or.at(rows[i], v >> 4,
+                         (np.uint16(1) << (v & 15)).astype(np.uint16))
+    return rows
+
+
+@pytest.mark.parametrize("cards", [(0, 1, 4096), (17, 4095, 300)],
+                         ids=["edges", "mixed"])
+def test_array_coverage_by_matmul_exact(cards):
+    """The one-hot-product array lift places every value exactly, including
+    65535 (the padding fill's value), a full 4096-value row and card 0."""
+    rng = np.random.default_rng(sum(cards))
+    sets = [np.sort(rng.choice(1 << 16, c, replace=False)) for c in cards]
+    if cards[1] == 1:
+        sets[1] = np.asarray([65535])
+    got = D.array_coverage_by_matmul(
+        jnp.asarray(_packed_rows(sets)).reshape(-1, 32, 128),
+        jnp.asarray(cards, jnp.int32))
+    np.testing.assert_array_equal(np.asarray(got).reshape(len(cards), -1),
+                                  _bitmap_rows(sets))
+
+
+@pytest.mark.parametrize("n_rows", [300, 257 * 3], ids=["divisor", "padded"])
+def test_lift_rows_chunked_over_many_rows(n_rows):
+    """Stacks taller than one lift chunk are lifted chunk by chunk (by a
+    divisor of the row count, or padded to whole chunks) to the same
+    bitmaps, for every kind, in the u32 word view."""
+    rng = np.random.default_rng(n_rows)
+    kinds = rng.integers(0, 3, n_rows).astype(np.int32)   # empty/array/bitmap
+    sets = [np.sort(rng.choice(1 << 16, int(rng.integers(1, 64)),
+                               replace=False)) for _ in range(n_rows)]
+    packed, bits = _packed_rows(sets), _bitmap_rows(sets)
+    rows = np.where((kinds == D.KIND_BITMAP)[:, None], bits, packed)
+    want = np.where((kinds == D.KIND_EMPTY)[:, None], 0, bits)
+    cards = np.asarray([len(v) for v in sets], np.int32)
+    got = D.lift_rows(jnp.asarray(rows)[None], jnp.asarray(kinds)[None],
+                      jnp.asarray(cards)[None],
+                      jnp.zeros((1, n_rows), jnp.int32), "matmul")
+    np.testing.assert_array_equal(np.asarray(D.from_words(got[0])), want)
+    np.testing.assert_array_equal(np.asarray(D.to_words(jnp.asarray(want))),
+                                  np.asarray(got[0]))
+
+
+@pytest.mark.parametrize("array_lift", sorted(D.ARRAY_LIFTS))
+def test_lift_rows_every_kind(array_lift):
+    """Each array lift, inside ``lift_rows``, gives the oracle's bitmap for
+    rows of every kind: empty, array (65535 and a full row included),
+    bitmap and run."""
+    rng = np.random.default_rng(11)
+    arrays = [np.asarray([65535]), np.arange(4096) * 16,
+              np.sort(rng.choice(1 << 16, 300, replace=False))]
+    bitmap = np.sort(rng.choice(1 << 16, 9000, replace=False))
+    ranges = [(5, 40), (100, 4000), (65500, 65536)]
+    run = jr.from_ranges(ranges, 1)
+    assert int(run.kind[0]) == jr.KIND_RUN
+    rows = np.concatenate([np.zeros((1, jr.ROW_WORDS), np.uint16),
+                           _packed_rows(arrays), _bitmap_rows([bitmap]),
+                           np.asarray(run.data[:1])])
+    kinds = np.asarray([jr.KIND_EMPTY] + [jr.KIND_ARRAY] * 3
+                       + [jr.KIND_BITMAP, jr.KIND_RUN], np.int32)
+    cards = np.asarray([0] + [len(a) for a in arrays] + [bitmap.size,
+                       int(run.card[0])], np.int32)
+    nruns = np.asarray([0] * 5 + [len(ranges)], np.int32)
+    got = D.lift_rows(jnp.asarray(rows), jnp.asarray(kinds),
+                      jnp.asarray(cards), jnp.asarray(nruns), array_lift)
+    run_vals = np.concatenate([np.arange(a, b) for a, b in ranges])
+    want = np.concatenate([np.zeros((1, jr.ROW_WORDS), np.uint16),
+                           _bitmap_rows(arrays + [bitmap, run_vals])])
+    np.testing.assert_array_equal(np.asarray(D.from_words(got)), want)
+
+
+def test_stacked_dispatch_shared_query_matches_broadcast():
+    """A query slab shared by all N stacked slabs (u16[C, 4096]) gives the
+    same hits and cards as the query broadcast to every slab, on the Pallas
+    path and the XLA reference."""
+    rng = np.random.default_rng(7)
+    N, C = 3, 4
+    sets = [np.sort(rng.choice(1 << 16, int(rng.integers(1, 3000)),
+                               replace=False)) for _ in range(N * C + C)]
+    a = jnp.asarray(_packed_rows(sets[: N * C]).reshape(N, C, -1))
+    q = jnp.asarray(_bitmap_rows(sets[N * C:]))
+    ka = np.full((N, C), D.KIND_ARRAY)
+    ca = np.asarray([len(v) for v in sets[: N * C]]).reshape(N, C)
+    cq = np.broadcast_to([len(v) for v in sets[N * C:]], (N, C))
+    z = np.zeros((N, C))
+    meta = jnp.asarray(np.stack([ka, np.full((N, C), D.KIND_BITMAP), ca, cq,
+                                 z, z], axis=2).reshape(N, 6 * C), jnp.int32)
+    want_h, want_c = K.intersect_dispatch_stacked_pallas(
+        a, jnp.broadcast_to(q, (N, C, q.shape[-1])), meta)
+    for use_pallas in (True, False):
+        h, c = kops.intersect_dispatch_stacked(a, q, meta,
+                                               use_pallas=use_pallas,
+                                               interpret=use_pallas)
+        np.testing.assert_array_equal(np.asarray(h), np.asarray(want_h))
+        np.testing.assert_array_equal(np.asarray(c), np.asarray(want_c))
+    for n in range(N):
+        for i in range(C):
+            want = len(set(sets[n * C + i].tolist())
+                       & set(sets[N * C + i].tolist()))
+            assert int(want_c[n, i]) == want
 
 
 def test_container_op_empty_columns_skip():

@@ -87,12 +87,8 @@ def test_sharded_reduce_matches_single_device_bytes():
 def _child() -> None:
     """Runs in the 2-device subprocess (XLA_FLAGS already in the env)."""
     import jax.numpy as jnp  # noqa: F401
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-
-    try:
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
 
     from repro import search
     from repro.core import py_roaring as pr
@@ -157,8 +153,13 @@ def _child() -> None:
     qs = [search.and_(search.term("w0"), search.term(f"w{i}"))
           for i in range(1, 5)]
     local = search.SearchService(idx, max_batch=4).search_many(qs, "docs")
-    remote = search.SearchService(sharded_idx,
-                                  max_batch=4).search_many(qs, "docs")
+    svc = search.SearchService(sharded_idx, max_batch=4)
+    remote = svc.search_many(qs, "docs")
+    # the hot-term cache, where every query kernel runs, stays on one
+    # device: a Pallas kernel is not partitioned over a mesh
+    c = svc._cache
+    assert all(len(x.devices()) == 1
+               for x in (c.payload, c.cards, c.kinds, c.nruns))
     for q, l, r in zip(qs, local, remote):
         assert np.array_equal(l, r), f"sharded service diverged on {q}"
         t0, t1 = q.children

@@ -191,11 +191,8 @@ def test_grad_compression_clustered_indices_use_bitmap_containers():
 
 def test_compressed_crosspod_mean_matches_dense_topk():
     """shard_map over a fake 2-pod mesh: compressed mean == mean of top-k."""
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:          # older jax: experimental namespace
-        from jax.experimental.shard_map import shard_map
     from repro.grad_comp import compressed_crosspod_mean
 
     if jax.device_count() < 2:
