@@ -219,10 +219,10 @@ def _topk_memory(index, term: str) -> None:
     st = index.stack
     args = (st.payload, st.cards, st.kinds, st.nruns,
             *E._align_query(st, index.posting(term)))
-    mem = E._stack_scores.lower(*args).compile().memory_analysis()
+    mem = E.index_score.lower(*args).compile().memory_analysis()
     dev = jax.devices()[0]
     before = dev.memory_stats() or {}
-    jax.block_until_ready(E._stack_scores(*args))
+    jax.block_until_ready(E.index_score(*args))
     after = dev.memory_stats() or {}
     log("search: one top-k scoring of "
         f"{st.kinds.shape[0]} x {index.C} rows: compiled "

@@ -590,11 +590,12 @@ def _align_query(stack: RoaringSlab, query: SlabLike):
 
 
 @jax.jit
-def _stack_scores(data, card, kind, nruns, qd, qc, qk, qr):
+def index_score(data, card, kind, nruns, qd, qc, qk, qr):
     """Per-slab |slab_n ∩ query| via the stacked batched-meta dispatch: the
     query rows are shared by all N slabs, never broadcast in HBM, and the
     dispatch's hit rows are dead code under this jit — only the fused
-    cardinalities are computed."""
+    cardinalities are computed. Its program is ``jit_index_score`` in a
+    device trace."""
     from repro.kernels.roaring import ops as _kops
     N, C = kind.shape
     meta = jnp.stack([
@@ -614,7 +615,7 @@ def batched_and_card(stack: RoaringSlab, query: SlabLike) -> jax.Array:
     materialized or canonicalized.
     """
     qd, qc, qk, qr = _align_query(stack, query)
-    return _stack_scores(stack.payload, stack.cards, stack.kinds, stack.nruns,
+    return index_score(stack.payload, stack.cards, stack.kinds, stack.nruns,
                          qd, qc, qk, qr)
 
 
@@ -647,7 +648,7 @@ def batched_and_card_sharded(stack: RoaringSlab, query: SlabLike,
     query = jax.device_put(query, NamedSharding(mesh, P()))
     qd, qc, qk, qr = _align_query(stack, query)
     f = jax.shard_map(
-        _stack_scores, mesh=mesh,
+        index_score, mesh=mesh,
         in_specs=(P(axis), P(axis), P(axis), P(axis), P(), P(), P(), P()),
         out_specs=P(axis))
     return f(stack.payload, stack.cards, stack.kinds, stack.nruns,

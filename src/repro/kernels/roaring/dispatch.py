@@ -327,8 +327,15 @@ def lift_rows(rows, kind, card, nruns, array_lift: str):
     bitmap domain, AND / OR / ANDNOT and the cardinality are word ops and
     popcounts, which is all the Pallas kernel does. Array rows are lifted
     by ``array_lift``, run rows by scatter, in chunks of rows so the
-    temporaries stay bounded for a stack of any height.
+    temporaries stay bounded for a stack of any height. Every op it emits
+    sits under the named scope ``roaring.lift``, the lift's stable name in
+    a device trace.
     """
+    with jax.named_scope("roaring.lift"):
+        return _lift_chunked(rows, kind, card, nruns, array_lift)
+
+
+def _lift_chunked(rows, kind, card, nruns, array_lift):
     lead = kind.shape
     R = kind.size
     flat = (rows.reshape(R, *ROW_SHAPE), kind.reshape(R), card.reshape(R),

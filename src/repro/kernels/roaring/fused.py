@@ -229,7 +229,8 @@ def word_tape_pallas(operands, plan: FusedPlan, *, interpret: bool = True):
     either ``M`` or 1 (one set of leaves shared by all M, read per column
     block without being broadcast in HBM). Returns (words u32[M, C, 16,
     128], card i32[M, C]). Grid ``(M, C/tb)`` with ``tb`` rows per step; the
-    kernel holds no scalar state.
+    kernel holds no scalar state. Its stable name in a device trace is
+    ``roaring_word_tape``.
     """
     M = max(o.shape[0] for o in operands)
     C = operands[0].shape[2]
@@ -259,6 +260,7 @@ def word_tape_pallas(operands, plan: FusedPlan, *, interpret: bool = True):
         out_shape=[out((M, C, *WORD_SHAPE), jnp.uint32),
                    out((M, C, 1, 128), jnp.int32)],
         interpret=interpret,
+        name="roaring_word_tape",
     )(*operands)
     return words, jnp.sum(lanes[:, :, 0, :], axis=-1)
 

@@ -10,7 +10,11 @@ model.
 Off by default: ``enable()`` flips the tracing flag *and* subscribes the
 launch-event hook in ``kernels/roaring/ops.py`` so every kernel dispatch
 increments ``roaring.launches{entry,backend}`` and lands as an event on the
-innermost open span. ``disable()`` undoes both. The metrics registry itself
+innermost open span. ``disable()`` undoes both. The hook fires at Python
+dispatch time: an eager dispatch counts once per call, a dispatch inside a
+``jax.jit`` program once per trace of that program, never per call. Spans
+count calls: the search service opens one ``search.execute`` per batch
+program and one ``search.score`` per scoring. The metrics registry itself
 has no switch — bare-int counters (ladder failures, cache hits) are cheap
 enough to stay always-on — but instrumentation sites that cost real work
 (host syncs for kind histograms, gauge refreshes, span bookkeeping) gate on
@@ -26,15 +30,16 @@ from repro.obs.metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                                reset_metrics)
 from repro.obs.report import (collect, environment, launch_crosscheck,
                               render_text, write_report)
-from repro.obs.trace import (Span, current_span, reset_traces, span,
-                             span_trees, tracing)
+from repro.obs.trace import (Span, current_span, record_span,
+                             reset_traces, span, span_trees, tracing)
 from repro.obs import trace as _trace
 
 __all__ = [
     # switches
     "enable", "disable", "enabled", "telemetry_scope",
     # tracing
-    "Span", "span", "current_span", "span_trees", "reset_traces",
+    "Span", "span", "record_span", "current_span", "span_trees",
+    "reset_traces",
     # metrics
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "registry",
     "reset_metrics", "record_kinds", "KIND_NAMES",

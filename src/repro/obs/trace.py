@@ -7,14 +7,22 @@ thread-local active-span stack; a span opened while another is active
 becomes its child, and completed *root* spans are collected into a bounded
 process-global list exportable as a span tree (``span_trees()``).
 
+While enabled, each span also opens a ``jax.profiler.TraceAnnotation`` of
+the same name, so a profiler trace holds the program's spans on the
+device trace's clock. ``record_span`` files a finished root span with
+explicit times on the ``time.monotonic`` clock (a request's wait in a
+queue, stamped at submit and closed at admission). The collection keeps
+the newest 4,096 roots; each root it drops counts in the registry counter
+``obs.spans_dropped``.
+
 Cost contract: tracing is **off by default**. When disabled, ``span()``
 returns a shared no-op context manager — one attribute read and two no-op
-method calls per span site, never an allocation — so instrumented hot paths
-(the jitted ``BitmapStore.query`` dispatch wrapper) pay well under the 5%
-overhead budget ``benchmarks/obs_bench.py`` gates in CI. Spans wrap
-*dispatch* (Python-level phases around jitted calls); they never trace into
-kernels — inside a ``jax.jit`` trace the span body runs once at trace time
-and costs nothing per launch afterwards.
+method calls per span site, never an allocation, and JAX is not imported —
+so instrumented hot paths (the jitted ``BitmapStore.query`` dispatch
+wrapper) pay well under the 5% overhead budget ``benchmarks/obs_bench.py``
+gates in CI. Spans wrap *dispatch* (Python-level phases around jitted
+calls); they never trace into kernels — inside a ``jax.jit`` trace the span
+body runs once at trace time and costs nothing per launch afterwards.
 """
 
 from __future__ import annotations
@@ -23,8 +31,8 @@ import threading
 import time
 from typing import Any, Dict, List, Optional
 
-__all__ = ["Span", "span", "current_span", "span_trees", "reset_traces",
-           "set_tracing", "tracing"]
+__all__ = ["Span", "span", "record_span", "current_span", "span_trees",
+           "reset_traces", "set_tracing", "tracing"]
 
 _MAX_ROOT_SPANS = 4096         # bounded collection: drop oldest roots
 
@@ -63,12 +71,13 @@ class Span:
 
     __slots__ = ("name", "attrs", "events", "children", "t0", "t1", "status")
 
-    def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None):
+    def __init__(self, name: str, attrs: Optional[Dict[str, Any]] = None,
+                 t0: Optional[float] = None):
         self.name = name
         self.attrs: Dict[str, Any] = dict(attrs) if attrs else {}
         self.events: List[Dict[str, Any]] = []
         self.children: List[Span] = []
-        self.t0 = time.monotonic()
+        self.t0 = time.monotonic() if t0 is None else t0
         self.t1: Optional[float] = None
         self.status = "open"
 
@@ -129,7 +138,7 @@ class span:
     before propagating — fallback rungs show up as errored child spans.
     """
 
-    __slots__ = ("_name", "_attrs", "_span")
+    __slots__ = ("_name", "_attrs", "_span", "_annotation")
 
     def __init__(self, name: str, **attrs: Any):
         self._name = name
@@ -139,6 +148,10 @@ class span:
     def __enter__(self):
         if not _ENABLED:
             return _NULL_SPAN
+        from jax.profiler import TraceAnnotation
+
+        self._annotation = TraceAnnotation(self._name)
+        self._annotation.__enter__()
         s = Span(self._name, self._attrs)
         st = _stack()
         if st:
@@ -153,6 +166,7 @@ class span:
             return False
         self._span = None
         s.t1 = time.monotonic()
+        self._annotation.__exit__(None, None, None)
         if etype is not None:
             s.status = "error"
             s.attrs.setdefault("error", etype.__name__)
@@ -165,11 +179,34 @@ class span:
                 st.pop()
             st.pop()
         if not st:
-            with _LOCK:
-                _FINISHED.append(s)
-                if len(_FINISHED) > _MAX_ROOT_SPANS:
-                    del _FINISHED[: len(_FINISHED) - _MAX_ROOT_SPANS]
+            _file_root(s)
         return False
+
+
+def _file_root(s: Span) -> None:
+    """Collect a finished root, dropping the oldest past the cap (each
+    drop counted in ``obs.spans_dropped``)."""
+    with _LOCK:
+        _FINISHED.append(s)
+        dropped = len(_FINISHED) - _MAX_ROOT_SPANS
+        if dropped > 0:
+            del _FINISHED[:dropped]
+    if dropped > 0:
+        from repro.obs.metrics import registry
+
+        registry().counter("obs.spans_dropped").inc(dropped)
+
+
+def record_span(name: str, t0: float, t1: float, **attrs: Any) -> None:
+    """File a finished root span ``name`` over ``[t0, t1]`` (seconds on the
+    ``time.monotonic`` clock), whatever span is open. Nothing while tracing
+    is disabled."""
+    if not _ENABLED:
+        return
+    s = Span(name, attrs, t0)
+    s.t1 = t1
+    s.status = "ok"
+    _file_root(s)
 
 
 def current_span() -> Optional[Span]:

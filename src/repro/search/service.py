@@ -33,9 +33,21 @@ Admission mirrors ``serve.engine``: a FIFO queue, same-signature packing up
 to ``max_batch``, capacity backpressure when a batch's distinct terms would
 overflow the cache (the deferred request stays queued and ``requeues``
 counts it), and the shared admission-queue gauge vocabulary
-(``obs.publish_service_gauges`` under the ``search.`` prefix). Per-request
-submit-to-completion latency lands in the ``search.latency_us`` log2
-histogram.
+(``obs.publish_service_gauges`` under the ``search.`` prefix).
+
+While telemetry is on, every host phase of ``step`` is a span carrying the
+request ids it serves (``rid`` / ``rids``): ``search.admit`` (``queued``,
+the queue length it walked), ``search.cache``, ``search.execute`` (one per
+batch program) and inside it ``search.slots`` (the batch's cache slots
+moved to the device), ``search.score`` (one per top-k scoring: the query slab's
+build and the scoring's dispatch), ``search.sync`` (each host pull of
+device results) and ``search.decode``; ``search.plan`` covers ``submit``,
+and each admitted request files ``search.queued`` (submit to admission).
+``search.execute`` and ``search.score`` carry ``rows_lifted``: the rows
+their program hands to ``dispatch.lift_rows`` on the chip's path (the fused
+batch program; the Pallas scoring), counted per call from the program's
+static shapes. With telemetry off the service
+writes nothing per request.
 """
 
 from __future__ import annotations
@@ -144,11 +156,18 @@ def lower(q: Query) -> Tuple[_engine.Expr, Tuple[str, ...]]:
 
 # the cache's two programs: the sharded fetch (one batched gather from the
 # possibly mesh-sharded posting stack) and the fill of the cache's slots
-fetch_rows = jax.jit(lambda sp, sc, sk, sn, rows:
-                     (sp[rows], sc[rows], sk[rows], sn[rows]))
-fill_slots = jax.jit(lambda cp, cc, ck, cn, sp, sc, sk, sn, sl:
-                     (cp.at[sl].set(sp), cc.at[sl].set(sc),
-                      ck.at[sl].set(sk), cn.at[sl].set(sn)))
+# (``jit_search_fetch`` / ``jit_search_fill`` in a device trace)
+def search_fetch(sp, sc, sk, sn, rows):
+    return sp[rows], sc[rows], sk[rows], sn[rows]
+
+
+def search_fill(cp, cc, ck, cn, sp, sc, sk, sn, sl):
+    return (cp.at[sl].set(sp), cc.at[sl].set(sc), ck.at[sl].set(sk),
+            cn.at[sl].set(sn))
+
+
+fetch_rows = jax.jit(search_fetch)
+fill_slots = jax.jit(search_fill)
 
 
 class _SlabCache:
@@ -234,9 +253,10 @@ def query_programs(expr: _engine.Expr, B: int, L: int, C: int,
     ``tile(arange(C), B)``), so one engine evaluation covers the whole
     batch and per-request results are the ``[B, C]`` unflattening. Returns
     per-request counts ``i32[B]``, or with ``want_rows`` the per-row cards
-    ``i32[B, C]`` and bitmap-domain rows ``u16[B, C, 4096]``.
+    ``i32[B, C]`` and bitmap-domain rows ``u16[B, C, 4096]``. Their programs
+    are ``jit_search_batch_fused`` / ``jit_search_batch_per_op``.
     """
-    def make(ev):
+    def make(ev, name):
         def f(payload, cards, kinds, nruns, slots):
             keys_flat = jnp.tile(jnp.arange(C, dtype=jnp.int32), B)
 
@@ -253,9 +273,24 @@ def query_programs(expr: _engine.Expr, B: int, L: int, C: int,
                 return jnp.sum(rc, axis=1)
             words = jr._lift_rows(data, card, kind)
             return rc, words.reshape(B, C, jr.ROW_WORDS)
+        f.__name__ = name
         return f
 
-    return make(_engine._fused_eval), make(_engine._eval)
+    return (make(_engine._fused_eval, "search_batch_fused"),
+            make(_engine._eval, "search_batch_per_op"))
+
+
+def batch_rows_lifted(B: int, L: int, C: int, want_rows: bool) -> int:
+    """Rows the fused batch program hands to ``dispatch.lift_rows``: its
+    ``L`` leaves' rows for each of ``B`` requests, and with ``want_rows``
+    the ``B`` result rows."""
+    return (L + want_rows) * B * C
+
+
+def score_rows_lifted(n_rows: int, C: int) -> int:
+    """Rows one top-k scoring hands to ``dispatch.lift_rows`` on the chip:
+    every stack row, and the query's rows once."""
+    return (n_rows + 1) * C
 
 
 # =============================================================================
@@ -318,33 +353,38 @@ class SearchService:
         """Lower + enqueue one query; returns the request id."""
         if mode not in MODES:
             raise ValueError(f"mode must be one of {MODES}, got {mode!r}")
-        with obs.span("search.plan", mode=mode):
+        rid = self._next_rid
+        with obs.span("search.plan", mode=mode, rid=rid):
             expr, terms = lower(q)
         if len(terms) > self.cache_slots:
             raise ValueError(
                 f"query has {len(terms)} distinct terms but the cache holds "
                 f"{self.cache_slots} slots — it can never be admitted")
-        rid = self._next_rid
         self._next_rid += 1
         self.queue.append(_Request(rid, expr, terms, mode,
                                    k if mode == "topk" else 0,
-                                   time.perf_counter()))
+                                   time.monotonic()))
         return rid
 
     def step(self) -> List[int]:
         """One service iteration: admit a same-signature batch, make its
         terms resident, execute it as one stacked launch. Returns the
         completed request ids."""
-        batch = self._admit()
+        with obs.span("search.admit", queued=len(self.queue)):
+            batch = self._admit()
         if batch:
+            rids = [r.rid for r in batch]
             batch_terms = []
             for r in batch:
                 batch_terms.extend(t for t in r.terms
                                    if t not in batch_terms)
-            with obs.span("search.cache", terms=len(batch_terms)):
+            with obs.span("search.cache", terms=len(batch_terms), rids=rids):
                 slot_of = self._cache.ensure(batch_terms)
             with obs.span("search.execute", batch=len(batch),
-                          mode=batch[0].mode):
+                          mode=batch[0].mode, rids=rids,
+                          rows_lifted=batch_rows_lifted(
+                              len(batch), len(batch[0].terms), self.index.C,
+                              batch[0].mode != "count")):
                 self._execute(batch, slot_of)
         self.steps_run += 1
         if obs.enabled():
@@ -425,6 +465,10 @@ class SearchService:
             batch.append(r)
             terms = grown
         self.queue = remaining
+        if obs.enabled():
+            now = time.monotonic()
+            for r in batch:
+                obs.record_span("search.queued", r.t_submit, now, rid=r.rid)
         return batch
 
     # -- execution ------------------------------------------------------------
@@ -443,40 +487,45 @@ class SearchService:
             self._fns[key] = self._build_fns(expr, B, L, mode != "count")
         fused_fn, per_op_fn = self._fns[key]
         c = self._cache
-        slots = jnp.asarray(
-            np.asarray([[slot_of[t] for t in r.terms] for r in batch],
-                       np.int32))
+        rids = [r.rid for r in batch]
+        with obs.span("search.slots", rids=rids):
+            slots = jnp.asarray(
+                np.asarray([[slot_of[t] for t in r.terms] for r in batch],
+                           np.int32))
         args = (c.payload, c.cards, c.kinds, c.nruns, slots)
         out = _engine._run_query(
             lambda: fused_fn(*args), lambda: per_op_fn(*args),
             self.fused, self.backend, self.max_retries, self.backoff_s)
         if mode == "count":
-            counts = np.asarray(out)
+            with obs.span("search.sync", rids=rids):
+                counts = np.asarray(out)
             for i, r in enumerate(batch):
-                self._finish(r, int(counts[i]))
+                self._results[r.rid] = int(counts[i])
             return
         rc, words = out
         if mode == "docs":
-            words_np = np.asarray(words)
-            for i, r in enumerate(batch):
-                self._finish(r, _decode_docs(words_np[i]))
+            with obs.span("search.sync", rids=rids):
+                words_np = np.asarray(words)
+            with obs.span("search.decode", rids=rids):
+                for i, r in enumerate(batch):
+                    self._results[r.rid] = _decode_docs(words_np[i])
             return
-        zeros = jnp.zeros((self.index.C,), jnp.int32)
+        C = self.index.C
+        zeros = jnp.zeros((C,), jnp.int32)
         for i, r in enumerate(batch):
-            qslab = roaring.RoaringSlab(
-                keys=self._keys_row,
-                kinds=jnp.where(rc[i] > 0, jr.KIND_BITMAP,
-                                jr.KIND_EMPTY).astype(jnp.int32),
-                cards=rc[i], nruns=zeros, payload=words[i], C=self.index.C)
-            scores, rows = self.index.topk(qslab, r.k)
-            self._finish(r, [(self.index.term_of(int(row)), int(s))
-                             for s, row in zip(np.asarray(scores),
-                                               np.asarray(rows))])
-
-    def _finish(self, r: _Request, value) -> None:
-        self._results[r.rid] = value
-        obs.registry().histogram("search.latency_us").record(
-            (time.perf_counter() - r.t_submit) * 1e6)
+            with obs.span("search.score", rid=r.rid,
+                          rows_lifted=score_rows_lifted(self.index.n_rows,
+                                                        C)):
+                qslab = roaring.RoaringSlab(
+                    keys=self._keys_row,
+                    kinds=jnp.where(rc[i] > 0, jr.KIND_BITMAP,
+                                    jr.KIND_EMPTY).astype(jnp.int32),
+                    cards=rc[i], nruns=zeros, payload=words[i], C=C)
+                scores, rows = self.index.topk(qslab, r.k)
+            with obs.span("search.sync", rids=[r.rid]):
+                scores, rows = np.asarray(scores), np.asarray(rows)
+            self._results[r.rid] = [(self.index.term_of(int(row)), int(s))
+                                    for s, row in zip(scores, rows)]
 
 
 def _decode_docs(words: np.ndarray) -> np.ndarray:

@@ -112,6 +112,145 @@ def test_spans_are_thread_local():
     assert names == ["main-root", "thread-root"]  # two roots, no nesting
 
 
+def test_record_span_files_a_root_with_its_times():
+    obs.record_span("search.queued", 1.0, 2.0, rid=3)    # disabled: nothing
+    assert obs.span_trees() == []
+    obs.enable()
+    with obs.span("outer"):
+        obs.record_span("search.queued", 10.0, 12.5, rid=7)
+    queued, outer = obs.span_trees()
+    assert (queued.name, queued.t0, queued.t1) == ("search.queued", 10.0,
+                                                   12.5)
+    assert queued.duration_s == 2.5 and queued.status == "ok"
+    assert queued.attrs == {"rid": 7} and queued.children == []
+    assert outer.name == "outer" and outer.children == []
+
+
+def test_a_dropped_root_is_counted(monkeypatch):
+    from repro.obs import trace as obs_trace
+
+    monkeypatch.setattr(obs_trace, "_MAX_ROOT_SPANS", 3)
+    obs.enable()
+    for i in range(5):
+        with obs.span(f"s{i}"):
+            pass
+    assert [r.name for r in obs.span_trees()] == ["s2", "s3", "s4"]
+    assert obs.registry().value("obs.spans_dropped") == 2
+    obs.record_span("late", 0.0, 1.0)
+    assert obs.registry().value("obs.spans_dropped") == 3
+
+
+# =============================================================================
+# the search service's spans
+# =============================================================================
+
+@pytest.fixture(scope="module")
+def posting_index():
+    from repro import search
+
+    rng = np.random.default_rng(13)
+    n_docs = 70_000                                    # C = 2
+    postings = {f"t{i}": np.unique(rng.integers(0, n_docs, 30_000 // (i + 1)))
+                for i in range(6)}
+    return search.PostingIndex.from_postings(postings, n_docs)
+
+
+def _q(a="t0", b="t1"):
+    from repro import search
+
+    return search.and_(search.term(a), search.term(b))
+
+
+def _flat(spans):
+    out = []
+    for s in spans:
+        out.append(s)
+        out += _flat(s.children)
+    return out
+
+
+def _host_event_names(log_dir):
+    from jax.profiler import ProfileData
+
+    (path,) = Path(log_dir).rglob("*.xplane.pb")
+    names = set()
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            names.update(ev.name for ev in line.events)
+    return names
+
+
+def test_enabled_spans_are_profiler_annotations(posting_index, tmp_path):
+    """While enabled, each span of a service step is a host annotation of
+    the profile under its own name; disabled spans write none."""
+    import jax
+
+    from repro import search
+
+    svc = search.SearchService(posting_index, max_batch=2)
+    svc.search(_q(), "count")                           # compile
+    for on in (False, True):
+        (obs.enable if on else obs.disable)()
+        log_dir = tmp_path / str(on)
+        jax.profiler.start_trace(str(log_dir))
+        svc.search(_q(), "count")
+        jax.profiler.stop_trace()
+        names = {n for n in _host_event_names(str(log_dir))
+                 if n.startswith(("search.", "index."))}
+        if on:
+            assert names == {"search.plan", "search.admit", "search.cache",
+                             "search.execute", "search.slots", "index.rung",
+                             "search.sync"}
+        else:
+            assert names == set()
+
+
+def test_rows_lifted_is_counted_per_call(posting_index):
+    """Ten calls of one compiled batch program (and of the scoring) count
+    ``rows_lifted`` ten times, though each program was traced once."""
+    from repro import search
+    from repro.search import service as S
+
+    svc = search.SearchService(posting_index, max_batch=2)
+    svc.search(_q(), "topk", k=3)                       # compile
+    obs.enable()
+    for _ in range(10):
+        svc.search(_q(), "topk", k=3)
+    spans = _flat(obs.span_trees())
+    execs = [s for s in spans if s.name == "search.execute"]
+    scores = [s for s in spans if s.name == "search.score"]
+    C = posting_index.C
+    assert len(execs) == len(scores) == 10
+    assert sum(s.attrs["rows_lifted"] for s in execs) == \
+        10 * S.batch_rows_lifted(1, 2, C, True) == 10 * 3 * C
+    assert sum(s.attrs["rows_lifted"] for s in scores) == \
+        10 * (posting_index.n_rows + 1) * C
+    assert {s.attrs["rid"] for s in scores} == {e.attrs["rids"][0]
+                                                for e in execs}
+    queued = [s for s in spans if s.name == "search.queued"]
+    assert len(queued) == 10 and all(s.duration_s >= 0 for s in queued)
+    # the launch hook fires per trace of a jitted program, not per call
+    assert obs.registry().total("roaring.launches") < 10
+
+
+def test_a_disabled_step_records_nothing_per_request(posting_index):
+    """With telemetry off, a step of four requests leaves the registry
+    exactly as a step of one does: nothing is written per request."""
+    from repro import search
+
+    svc = search.SearchService(posting_index, max_batch=4)
+    for b in (1, 4):                                    # compile both
+        svc.search_many([_q()] * b, "count")
+    snaps = []
+    for b in (1, 4):
+        obs.reset_metrics()
+        svc.search_many([_q()] * b, "count")
+        snaps.append(obs.registry().snapshot())
+    assert snaps[0] == snaps[1]
+    assert not snaps[0].get("histograms")
+    assert obs.span_trees() == []
+
+
 # =============================================================================
 # metrics registry
 # =============================================================================
