@@ -24,7 +24,12 @@ of a key-aligned stacked ``repro.roaring.RoaringSlab`` (``ndim == 2``) — or
     against one query in a single batched-meta dispatch launch
     (``kernels.roaring.ops.intersect_dispatch_stacked``), and the
     ``*_sharded`` variants ``shard_map`` the slab axis across a device mesh
-    (``launch/mesh.py``) with the query replicated.
+    (``launch/mesh.py``) with the query replicated;
+  * ``plan_scan`` / ``topk_by_scan`` score an
+    *immutable* stack without lifting it: a plan built once from the
+    stack's cards and kinds groups its rows into width classes, and each
+    class is read in place over the slots its containers fill
+    (``kernels.roaring.ops.card_scan``).
 
 ``execute`` returns a canonical ``repro.roaring.RoaringSlab``. Everything is
 jit-/vmap-compatible; expression shapes are static Python.
@@ -33,11 +38,13 @@ jit-/vmap-compatible; expression shapes are static Python.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from typing import Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 import repro.obs as obs
 from repro.core import jax_roaring as jr
@@ -50,6 +57,7 @@ __all__ = [
     "execute", "execute_card", "wide_union", "wide_intersect",
     "batched_and_card", "batched_and_card_sharded",
     "topk_by_card", "topk_by_card_sharded",
+    "ScanPlan", "plan_scan", "topk_by_scan",
     "union_many_batched", "launch_model",
     "DegradationStats", "degradation_stats", "reset_degradation",
 ]
@@ -627,6 +635,149 @@ def topk_by_card(stack: RoaringSlab, query: SlabLike, k: int):
     primitive).
     """
     return jax.lax.top_k(batched_and_card(stack, query), k)
+
+
+# =============================================================================
+# planned scoring: an immutable stack read where its containers lie
+# =============================================================================
+
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["keys", "groups", "places", "codes",
+                                "residual", "order"],
+                   meta_fields=["widths", "bitmaps", "rows_lifted",
+                                "slots_scanned", "slots_total"])
+@dataclasses.dataclass(frozen=True)
+class ScanPlan:
+    """How one immutable stack is scored against a query (``plan_scan``).
+
+    Class ``i`` holds the stack rows (terms) of width ``widths[i]``: every
+    array row of theirs holds at most that many values, so the scan reads
+    only their first ``widths[i]`` slots. The scan reads rows in groups of
+    ``scan.GROUP`` consecutive ones (a tile of the stack): ``groups[i]``
+    (i32[S_i]) are the groups holding the class's rows, ``places[i]``
+    (i32[S_i·GROUP]) flags the class's rows holding an array row (the rows
+    the kernel places), ``codes[i]`` (i32[S_i, GROUP, C]) their rows' scan
+    codes, 0 for rows of other classes; ``bitmaps[i]`` says the class
+    holds bitmap rows.
+    ``residual`` (i32[R]) are the rows holding a run row, scored by the
+    lift. Rows with no container are in no class and score 0. ``order``
+    (i32[N]) puts each row's score back in stack order from the classes'
+    scores (group by group), then the residual's, then one zero. ``keys``
+    is the stack's shared key row. The last three fields count one
+    scoring: rows handed to ``dispatch.lift_rows`` (the query's C and the
+    residual's), container slots read (every row of each group a class
+    visits at the class's width, its other classes' rows masked, and a
+    residual row's 4096), and slots in the stack.
+    """
+
+    keys: jax.Array
+    groups: Tuple[jax.Array, ...]
+    places: Tuple[jax.Array, ...]
+    codes: Tuple[jax.Array, ...]
+    residual: jax.Array
+    order: jax.Array
+    widths: Tuple[int, ...]
+    bitmaps: Tuple[bool, ...]
+    rows_lifted: int
+    slots_scanned: int
+    slots_total: int
+
+
+def plan_scan(stack: RoaringSlab) -> ScanPlan:
+    """The scan plan of a stack, from its cards and kinds (read to the host
+    once). A row's width is the next power of two at or above its largest
+    array card, from the lane width (128) up to the row width (4096); a
+    row holding a bitmap takes 4096 and one holding a run goes to the
+    residual. The class set is those widths that some row takes."""
+    from repro.kernels.roaring import scan as _scan
+
+    kinds, cards = np.asarray(stack.kinds), np.asarray(stack.cards)
+    N, C = kinds.shape
+    G = _scan.GROUP
+    largest = np.where(kinds == jr.KIND_ARRAY, cards, 0).max(axis=1)
+    width = np.full(N, _scan.LANES)
+    while (width < largest).any():
+        width = np.where(width < largest, 2 * width, width)
+    holds = [(kinds == k).any(axis=1) for k in (jr.KIND_BITMAP, jr.KIND_RUN)]
+    width[holds[0]] = jr.ROW_WORDS
+    scanned = (kinds != jr.KIND_EMPTY).any(axis=1) & ~holds[1]
+    widths = sorted({int(w) for w in width[scanned]})
+    # rows padded to whole groups; padding rows are in no class
+    codes = np.zeros((-(-N // G) * G, C), np.int32)
+    codes[:N] = _scan.scan_codes(kinds, cards)
+    residual = np.flatnonzero(holds[1])
+    order = np.full(N, -1, np.int64)
+    groups, places, class_codes, at = [], [], [], 0
+    for w in widths:
+        member = np.zeros(codes.shape[0], bool)
+        member[:N] = scanned & (width == w)
+        g = np.flatnonzero(member.reshape(-1, G).any(axis=1))
+        rows = (g[:, None] * G + np.arange(G)).reshape(-1)
+        hit = member[rows]
+        order[rows[hit]] = at + np.flatnonzero(hit)
+        groups.append(g)
+        places.append(hit & (codes[rows] > 0).any(axis=1))
+        class_codes.append(np.where(hit[:, None], codes[rows], 0).reshape(
+            g.size, G, C))
+        at += rows.size
+    order[residual] = at + np.arange(residual.size)
+    order[order < 0] = at + residual.size      # the trailing zero
+    i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
+    return ScanPlan(
+        keys=i32(np.asarray(stack.keys[0])),
+        groups=tuple(map(i32, groups)), places=tuple(map(i32, places)),
+        codes=tuple(map(i32, class_codes)),
+        residual=i32(residual), order=i32(order), widths=tuple(widths),
+        bitmaps=tuple(bool((c == _scan.BITMAP_CODE).any())
+                      for c in class_codes),
+        rows_lifted=C * (1 + residual.size),
+        slots_scanned=C * (sum(w * g.size * G for w, g in zip(widths, groups))
+                           + jr.ROW_WORDS * residual.size),
+        slots_total=N * C * jr.ROW_WORDS)
+
+
+def _scan_scores(payload, kinds, cards, nruns, plan: ScanPlan, query):
+    """i32[N] scores of ``plan``'s stack against ``query`` (traced)."""
+    from repro.kernels.roaring import dispatch as _D
+    from repro.kernels.roaring import ops as _kops
+
+    qd, qc, qk = jr._gather_raw(_to_internal(query), plan.keys)
+    lift = _D.device_array_lift()
+    q = _D.lift_rows(qd, qk, qc, jr._rows_nruns(qd, qk), lift)
+    parts = [_kops.card_scan(payload, g, p, c, q, width=w,
+                             bitmaps=b).reshape(-1)
+             for w, b, g, p, c in zip(plan.widths, plan.bitmaps, plan.groups,
+                                      plan.places, plan.codes)]
+    r = plan.residual
+    if r.shape[0]:
+        words = _D.lift_rows(payload[r], kinds[r], cards[r], nruns[r], lift)
+        parts.append(jnp.sum(jax.lax.population_count(words & q).astype(
+            jnp.int32), axis=(1, 2, 3)))
+    parts.append(jnp.zeros((1,), jnp.int32))
+    return jnp.concatenate(parts)[plan.order]
+
+
+def _planned_score(payload, kinds, cards, nruns, plan, query, k):
+    return jax.lax.top_k(_scan_scores(payload, kinds, cards, nruns, plan,
+                                      query), k)
+
+
+# whichever path scores, a device trace names the scoring program
+# ``jit_index_score``
+_planned_score.__name__ = "index_score"
+_planned_score = jax.jit(_planned_score, static_argnames="k")
+
+
+def topk_by_scan(stack: RoaringSlab, plan: ScanPlan, query: SlabLike,
+                 k: int):
+    """``topk_by_card`` for a stack with a scan plan: ``(scores i32[k],
+    indices i32[k])``, the same scores with the stack read in place over
+    the slots its containers fill (one ``card_scan`` launch per width
+    class) and only the query's rows (and any residual run-holding rows)
+    lifted. The scores are put back in stack order before ``top_k``, so
+    ties break as ``topk_by_card``'s."""
+    return _planned_score(stack.payload, stack.kinds, stack.cards,
+                          stack.nruns, plan, query, k=k)
 
 
 # =============================================================================

@@ -50,6 +50,7 @@ from . import dispatch as _d
 from . import fused as _f
 from . import kernel as _k
 from . import ref as _ref
+from . import scan as _s
 
 
 # -- per-launch controls ------------------------------------------------------
@@ -249,3 +250,29 @@ def intersect_dispatch_stacked(a_data, b_data, meta,
                                      "intersect_dispatch_stacked")
     return _intersect_dispatch_stacked(a_data, b_data, meta, use_pallas,
                                        interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("width", "bitmaps", "use_pallas",
+                                             "interpret"))
+def _card_scan(payload, groups, places, codes, q_words, width, bitmaps,
+               use_pallas, interpret):
+    if use_pallas or interpret:
+        return _s.card_scan_pallas(payload, groups, places, codes, q_words,
+                                   width=width, bitmaps=bitmaps,
+                                   interpret=not _d.on_tpu())
+    return _s.card_scan_ref(payload, groups, places, codes, q_words,
+                            width=width, bitmaps=bitmaps)
+
+
+def card_scan(payload, groups, places, codes, q_words, *, width: int,
+              bitmaps: bool, use_pallas: bool | None = None,
+              interpret: bool = False):
+    """Scores of one width class of a scan plan: i32[S, GROUP]
+    ``|term ∩ query|`` for the terms of the stack's row groups ``groups``
+    whose scan codes ``codes`` leaves unmasked, each read over its first
+    ``width`` slots (``scan.card_scan_pallas`` for the arguments). Pallas
+    kernel ``roaring_card_scan`` on TPU, the probing XLA mirror
+    elsewhere."""
+    use_pallas, interpret = _resolve(use_pallas, interpret, "card_scan")
+    return _card_scan(payload, groups, places, codes, q_words, width,
+                      bitmaps, use_pallas, interpret)

@@ -7,13 +7,18 @@ in one shot via the store's ``_stack_bitmaps`` placer — no per-term device
 gathers. Row 0 is reserved as the empty posting so queries over unknown
 terms resolve to well-formed empties instead of raising mid-batch.
 
+On one device the index scores top-k queries by a scan plan
+(``index.engine.plan_scan``) built once with it: each term's rows are read
+in place over the slots its containers fill.
+
 ``shard(mesh)`` partitions the *term* axis across a device mesh
 (``distributed.sharding.shard_postings``): each device owns a vocabulary
 slice, per-term row fetches are device-local, and the stack is padded with
 empty rows so the axis always divides. Batched query evaluation itself runs
 on the service's replicated hot-term cache; the sharded stack is the
 backing store those cache fills (the "sharded fetch") gather from, and what
-``topk_by_card_sharded`` scores against.
+``topk_by_card_sharded`` scores against (a sharded index has no scan
+plan: it lifts its whole stack per scoring).
 """
 
 from __future__ import annotations
@@ -45,7 +50,8 @@ class PostingIndex:
     (row 0 reserved empty, then one posting row per term in sorted term
     order); ``terms`` is the sorted vocabulary tuple; ``mesh`` is set on
     sharded instances (``shard`` returns a new index, the host metadata is
-    shared).
+    shared). ``plan`` is the scan plan of an index on one device, None
+    when sharded.
     """
 
     def __init__(self, terms: Tuple[str, ...], stack: roaring.RoaringSlab,
@@ -57,6 +63,7 @@ class PostingIndex:
         self.mesh = mesh
         self.axis = axis
         self._row = {t: EMPTY_ROW + 1 + i for i, t in enumerate(terms)}
+        self.plan = _engine.plan_scan(stack) if mesh is None else None
 
     # -- constructors ---------------------------------------------------------
     @classmethod
@@ -144,14 +151,14 @@ class PostingIndex:
 
     # -- scoring + accounting -------------------------------------------------
     def topk(self, query: roaring.RoaringSlab, k: int):
-        """Top-k terms by ``|posting ∩ query|``: one batched-meta dispatch
-        over all rows (sharded over the mesh when the index is sharded).
-        Returns ``(scores i32[k], rows i32[k])`` — map rows back with
-        ``term_of``."""
+        """Top-k terms by ``|posting ∩ query|``: the scan plan's program on
+        one device (``engine.topk_by_scan``), one batched-meta dispatch per
+        mesh device when the index is sharded. Returns ``(scores i32[k],
+        rows i32[k])`` — map rows back with ``term_of``."""
         if self.mesh is not None:
             return _engine.topk_by_card_sharded(self.stack, query, k,
                                                 self.mesh, axis=self.axis)
-        return _engine.topk_by_card(self.stack, query, k)
+        return _engine.topk_by_scan(self.stack, self.plan, query, k)
 
     def launch_model(self, expr) -> dict:
         """Analytic launch accounting for one lowered query expression —

@@ -45,9 +45,11 @@ device results) and ``search.decode``; ``search.plan`` covers ``submit``,
 and each admitted request files ``search.queued`` (submit to admission).
 ``search.execute`` and ``search.score`` carry ``rows_lifted``: the rows
 their program hands to ``dispatch.lift_rows`` on the chip's path (the fused
-batch program; the Pallas scoring), counted per call from the program's
-static shapes. With telemetry off the service
-writes nothing per request.
+batch program; the scoring: the query's rows and the scan plan's residual
+rows), counted per call from the program's static shapes. ``search.score``
+also carries ``slots_scanned`` and ``slots_total``: the container slots the
+scoring reads and the slots the stack holds (``score_cost``).
+With telemetry off the service writes nothing per request.
 """
 
 from __future__ import annotations
@@ -288,9 +290,22 @@ def batch_rows_lifted(B: int, L: int, C: int, want_rows: bool) -> int:
 
 
 def score_rows_lifted(n_rows: int, C: int) -> int:
-    """Rows one top-k scoring hands to ``dispatch.lift_rows`` on the chip:
-    every stack row, and the query's rows once."""
+    """Rows a scoring that lifts the whole stack (a sharded index's) hands
+    to ``dispatch.lift_rows``: every stack row, and the query's rows
+    once."""
     return (n_rows + 1) * C
+
+
+def score_cost(index: PostingIndex) -> dict:
+    """What one top-k scoring of ``index`` reads: ``rows_lifted``,
+    ``slots_scanned`` (container slots read, a lifted row's 4096) and
+    ``slots_total`` (the slots of the stack's rows)."""
+    total = index.n_rows * index.C * jr.ROW_WORDS
+    if index.plan is None:
+        return {"rows_lifted": score_rows_lifted(index.n_rows, index.C),
+                "slots_scanned": total, "slots_total": total}
+    return {"rows_lifted": index.plan.rows_lifted,
+            "slots_scanned": index.plan.slots_scanned, "slots_total": total}
 
 
 # =============================================================================
@@ -344,6 +359,7 @@ class SearchService:
         self.requeues = 0
         self._cache = _SlabCache(index, cache_slots)
         self._keys_row = jnp.asarray(np.arange(index.C, dtype=np.int32))
+        self._score_cost = score_cost(index)
         self._results: Dict[int, object] = {}
         self._fns: Dict[tuple, tuple] = {}
         self._next_rid = 0
@@ -513,9 +529,7 @@ class SearchService:
         C = self.index.C
         zeros = jnp.zeros((C,), jnp.int32)
         for i, r in enumerate(batch):
-            with obs.span("search.score", rid=r.rid,
-                          rows_lifted=score_rows_lifted(self.index.n_rows,
-                                                        C)):
+            with obs.span("search.score", rid=r.rid, **self._score_cost):
                 qslab = roaring.RoaringSlab(
                     keys=self._keys_row,
                     kinds=jnp.where(rc[i] > 0, jr.KIND_BITMAP,

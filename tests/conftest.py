@@ -16,8 +16,10 @@ import pytest
 # a worker that has compiled a few thousand programs reaches the kernel's
 # per-process limit (vm.max_map_count, 65,530 by default) and the next
 # compile segfaults. Past this many mappings the fixture below drops JAX's
-# compiled-program caches (only recompiles are lost).
-_MAPPINGS_BUDGET = 30_000
+# compiled-program caches (only recompiles are lost). One test may add
+# about 50,000 by itself (test_dispatch.py's set-algebra property compiles
+# anew for each drawn set size), so a test must start well below the limit.
+_MAPPINGS_BUDGET = 10_000
 
 
 def pytest_configure(config):

@@ -223,8 +223,15 @@ def test_rows_lifted_is_counted_per_call(posting_index):
     assert len(execs) == len(scores) == 10
     assert sum(s.attrs["rows_lifted"] for s in execs) == \
         10 * S.batch_rows_lifted(1, 2, C, True) == 10 * 3 * C
-    assert sum(s.attrs["rows_lifted"] for s in scores) == \
-        10 * (posting_index.n_rows + 1) * C
+    # the scan lifts the query's rows only: no posting holds a run row
+    assert sum(s.attrs["rows_lifted"] for s in scores) == 10 * C
+    assert all(s.attrs == {**S.score_cost(posting_index),
+                           "rid": s.attrs["rid"]} for s in scores)
+    # each class reads the one group of this index's 7 rows, all 16 rows
+    plan = posting_index.plan
+    assert scores[0].attrs["slots_scanned"] == \
+        C * 16 * sum(plan.widths) > 0
+    assert scores[0].attrs["slots_total"] == posting_index.n_rows * C * 4096
     assert {s.attrs["rid"] for s in scores} == {e.attrs["rids"][0]
                                                 for e in execs}
     queued = [s for s in spans if s.name == "search.queued"]

@@ -214,3 +214,62 @@ def test_sharded_service_programs_compile_for_v5e_2x2(topology, one_chip,
     with pytest.raises(NotImplementedError, match="partitioned"):
         jax.jit(fused).lower(*cache(replicated),
                              _spec((B, len(terms)), jnp.int32, replicated))
+
+
+# ------------------------------------------------- the planned top-k scoring
+# the width classes of the facet cell's 4,096 Zipf-sized terms: the groups
+# of 16 stack rows each class visits, from 128 slots to 4,096 (the last
+# holds the bitmap rows)
+_FACET_CLASSES = {128: 237, 256: 12, 512: 6, 1024: 3, 2048: 2, 4096: 2}
+
+
+@pytest.mark.parametrize("chunks", [C, 16], ids=["chunk_major", "row_major"])
+def test_planned_scoring_compiles_for_v5e(one_chip, chip_backend, chunks):
+    """``topk_by_scan`` over 4,097 stack rows with the facet cell's classes:
+    one ``roaring_card_scan`` launch per width class, no lifted words over
+    the stack, in well under the 9.59 GB of temporaries the whole-stack lift
+    takes. The stack has the layout the chip gives it (as every other
+    program over it expects): chunk-major at the cell's 135 chunk rows,
+    read in place with no copy; row-major at 16, transposed once per
+    scoring."""
+    import re
+
+    from repro import index as ix
+    from repro.kernels.roaring import scan as S
+    from repro.roaring import RoaringSlab
+
+    N = 4097
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    groups = _FACET_CLASSES.values()
+    plan = ix.ScanPlan(
+        keys=i32(chunks), groups=tuple(i32(g) for g in groups),
+        places=tuple(i32(g * S.GROUP) for g in groups),
+        codes=tuple(i32(g, S.GROUP, chunks) for g in groups),
+        residual=i32(0), order=i32(N), widths=tuple(_FACET_CLASSES),
+        bitmaps=(False,) * 5 + (True,), rows_lifted=chunks, slots_scanned=0,
+        slots_total=0)
+    stack = RoaringSlab(
+        payload=_spec((N, chunks, ROW), jnp.uint16, one_chip),
+        keys=i32(N, chunks), kinds=i32(N, chunks), cards=i32(N, chunks),
+        nruns=i32(N, chunks), C=chunks)
+    query = RoaringSlab(payload=_spec((chunks, ROW), jnp.uint16, one_chip),
+                        keys=i32(chunks), kinds=i32(chunks),
+                        cards=i32(chunks), nruns=i32(chunks), C=chunks)
+    compiled = jax.jit(lambda st, p, q: ix.topk_by_scan(st, p, q, 10)).lower(
+        stack, plan, query).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == \
+        len(_FACET_CLASSES)
+    stack_bytes = N * chunks * ROW * 2
+    copies = re.findall(rf"u16\[({N},{chunks}|{chunks},{N}),{ROW}\]"
+                        r"\S* (?:copy|transpose)\(", text)
+    assert len(copies) == (0 if chunks == C else 1)
+    assert compiled.memory_analysis().temp_size_in_bytes < \
+        len(copies) * stack_bytes + (1 << 30)
+    for dims in re.findall(r"u32\[([\d,]+)\]", text):
+        shape = [int(d) for d in dims.split(",")]
+        if shape[-2:] == [16, 128]:
+            assert np.prod(shape[:-2]) < N * chunks, shape
