@@ -17,8 +17,8 @@ same generated data, made from ``--seed``):
   modes.
 * **store** — ``BitmapStore.build`` over census-like records at census1881's
   row count (4,277,807, ``benchmarks/synth.REAL_SPECS``), BSI on the integer
-  columns; a dozen predicates through ``query``, ``count`` and ``sum_``,
-  fused and per-op.
+  columns; a dozen predicates through ``query`` and ``count``, fused and
+  per-op, and ``sum_`` / ``group_sum`` (grouped by ``cat0``).
 * **sharded** (``--chips 4`` only) — the same index sharded over four
   devices, ``SearchService`` on top, ``topk_by_card_sharded`` and
   ``batched_and_card_sharded``, each compared with the one-device index and
@@ -326,20 +326,24 @@ def store_phase(args, intended: str) -> dict:
                 got = store.query_indices(pred, fused=fused)
                 assert np.array_equal(got, want_rows), (pred, fused)
                 n += 1
-    # sum_ filters through a per-op query: the predicates already run so
-    eq_preds = [p for p, paths in preds if "Q" in paths]
-    for name, pred in (("int0", None), ("int1", eq_preds[0]),
-                       ("int0", eq_preds[-1])):
+    # a sum runs one program per predicate shape, the filter's tape inside
+    # the aggregation kernel; group_sum adds a group per value of cat0
+    for name, pred in (("int0", None), ("int1", preds[0][0]),
+                       ("int0", preds[-1][0])):
         mask = np.ones(args.rows, bool) if pred is None else \
             _mask(records, pred)
         assert store.sum_(name, pred) == int(records[name][mask].sum()), \
             (name, pred)
-        n += 1
+        want = {int(v): int(records[name][mask & (records["cat0"] == v)]
+                            .sum()) for v in np.unique(records["cat0"])}
+        assert store.group_sum(name, "cat0", pred) == want, (name, pred)
+        n += 2
     wall_s = time.perf_counter() - t0
     stats = store.cache_stats()
     assert stats["fallbacks"] == 0, stats
     log(f"store: {n} answers from {len(preds)} predicates (query, count, "
-        f"sum_; fused and per-op), all equal to numpy; query cache {stats}")
+        f"sum_, group_sum; fused and per-op), all equal to numpy; query "
+        f"cache {stats}")
     return {"setup_s": setup_s, "wall_s": wall_s, "answers": n}
 
 

@@ -97,10 +97,11 @@ def bitmap_to_array(words: np.ndarray) -> np.ndarray:
 
 def array_to_bitmap(arr: np.ndarray) -> np.ndarray:
     """Set the bits of a sorted u16 array in a fresh 1024-word bitmap."""
-    words = np.zeros(BITMAP_WORDS, dtype=_U64)
-    a = arr.astype(np.int64)
-    np.bitwise_or.at(words, a >> 6, (_U64(1) << (a & 63).astype(_U64)))
-    return words
+    bits = np.zeros(CHUNK_SIZE, bool)
+    bits[np.asarray(arr, np.int64)] = True
+    # little-endian bytes of little-endian bit order: value v is bit v % 64
+    # of word v // 64
+    return np.packbits(bits, bitorder="little").view("<u8").astype(_U64)
 
 
 # =============================================================================

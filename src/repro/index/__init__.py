@@ -11,7 +11,9 @@ Since the ``repro.roaring`` object API, the stacked-slab *type* is
     batched-meta dispatch kernel, ``shard_map`` sharding of the slab
     axis across a device mesh, and the planned scoring of an immutable
     stack (``plan_scan`` / ``topk_by_scan``: each row read in place over
-    the slots its containers fill).
+    the slots its containers fill), and the cards of many stack members
+    against one filter tree in one launch (``lower`` / ``lift_members`` /
+    ``pair_cards``: the store's grouped sums).
 
 ``SlabStack`` / ``stack_from_slabs`` / ``union_many_batched`` remain as
 deprecated shims over the ``repro.roaring`` equivalents.
@@ -24,7 +26,8 @@ from repro.index.engine import (And, AndNot, DegradationStats, Expr, Leaf, Or,
                                 ScanPlan, SlabLeaf, and_, andnot,
                                 batched_and_card, batched_and_card_sharded,
                                 degradation_stats, execute, execute_card,
-                                launch_model, leaf, or_, plan_scan,
+                                launch_model, leaf, lift_members, lower,
+                                or_, pair_cards, plan_scan,
                                 reset_degradation, topk_by_card,
                                 topk_by_card_sharded,
                                 topk_by_scan, union_many_batched,
@@ -39,6 +42,7 @@ __all__ = [
     "batched_and_card", "batched_and_card_sharded",
     "topk_by_card", "topk_by_card_sharded",
     "ScanPlan", "plan_scan", "topk_by_scan",
+    "lower", "lift_members", "pair_cards",
     "union_many_batched", "launch_model",
     "DegradationStats", "degradation_stats", "reset_degradation",
 ]

@@ -58,6 +58,7 @@ __all__ = [
     "batched_and_card", "batched_and_card_sharded",
     "topk_by_card", "topk_by_card_sharded",
     "ScanPlan", "plan_scan", "topk_by_scan",
+    "lower", "lift_members", "pair_cards",
     "union_many_batched", "launch_model",
     "DegradationStats", "degradation_stats", "reset_degradation",
 ]
@@ -635,6 +636,55 @@ def topk_by_card(stack: RoaringSlab, query: SlabLike, k: int):
     primitive).
     """
     return jax.lax.top_k(batched_and_card(stack, query), k)
+
+
+# =============================================================================
+# filtered pair cards: many stack members against one filter tree
+# =============================================================================
+
+def lower(expr: Expr) -> Tuple[_fused.FusedPlan, Tuple[int, ...]]:
+    """An expression over stack members as ``(plan, members)``: the tape of
+    its shape (hash-consed, so equal shapes give the same plan) and the
+    stack member each of the tape's operands reads."""
+    tree, order = _lower_tree(expr)
+    if not all(isinstance(e, Leaf) for e in order):
+        raise TypeError("lower takes an expression over stack members only")
+    return _fused.plan_tape(tree), tuple(e.i for e in order)
+
+
+def lift_members(stack: RoaringSlab, members: jax.Array) -> jax.Array:
+    """The rows of stack members ``members`` (i32[n], may be traced) as
+    bitmap-domain words u32[n, C, 16, 128] (``dispatch.lift_rows``).
+
+    Each member is taken by its own dynamic slice: as one gather, a few
+    members of a tall stack lower on a TPU to slices of the whole stack
+    (a copy of every member per call)."""
+    from repro.kernels.roaring import dispatch as _D
+
+    def take(x):
+        return jnp.stack([jax.lax.dynamic_index_in_dim(x, members[i],
+                                                       keepdims=False)
+                          for i in range(members.shape[0])])
+
+    return _D.lift_rows(take(stack.payload), take(stack.kinds),
+                        take(stack.cards), take(stack.nruns),
+                        _D.device_array_lift())
+
+
+def pair_cards(plan: Optional[_fused.FusedPlan], leaves, groups, slices
+               ) -> jax.Array:
+    """i32[max(G, 1), B] of ``|filter ∩ group_g ∩ slice_b|`` over every
+    chunk row, in one launch that reads each row once and builds no result
+    slab: the grouped-aggregation primitive (``sum`` of a bit-sliced
+    column by an equality column is ``Σ_b 2^b`` of a group's cards).
+
+    ``leaves`` (u32[L, C, 16, 128], ``lift_members``) are the operands of
+    ``plan``, the filter's tape (``lower``); both None: no filter.
+    ``groups`` (u32[G, ...]; None: one group of every row) and ``slices``
+    (u32[B, ...]) are bitmap-domain words too (``ops.group_card``)."""
+    from repro.kernels.roaring import ops as _kops
+
+    return _kops.group_card(leaves, groups, slices, plan)
 
 
 # =============================================================================

@@ -298,17 +298,33 @@ def from_words(words):
     return halves.astype(jnp.uint16).reshape(*words.shape[:-2], ROW_WORDS)
 
 
+def _one_run_coverage(rows):
+    """Coverage words of rows holding one run: the run ``(start, len-1)`` is
+    the row's first pair, and word ``w`` covers values ``16w`` to
+    ``16w + 15``, so its bits come from two clipped shifts, with no
+    scatter."""
+    start = rows[:, :1, :1].astype(jnp.int32)
+    end = start + rows[:, :1, 1:2].astype(jnp.int32) + 1
+    first = 16 * _flat_pos()[None]
+    lo = jnp.clip(start - first, 0, 16)
+    hi = jnp.clip(end - first, 0, 16)
+    return ((1 << hi) - (1 << lo)).astype(jnp.uint16)
+
+
 def _lift_flat(rows, kind, card, nruns, array_lift):
     """``lift_rows`` over flat ``[R, 32, 128]`` rows: bitmap rows pass
-    through, empty rows become zero, and each of the array / run lifts runs
-    only when the chunk holds a row of that kind."""
+    through, empty rows become zero, rows of one run are covered in place,
+    and each of the array / many-run lifts runs only when the chunk holds
+    a row of that kind."""
+    one_run = (kind == KIND_RUN) & (nruns == 1)
     bits = jnp.where((kind == KIND_BITMAP)[:, None, None], rows,
-                     jnp.zeros_like(rows))
-    lifts = ((KIND_ARRAY, lambda: ARRAY_LIFTS[array_lift](rows, card)),
-             (KIND_RUN, lambda: jax.vmap(coverage_by_scatter)(rows, nruns)))
-    for k, lift in lifts:
-        pred = kind == k
-
+                     jnp.where(one_run[:, None, None],
+                               _one_run_coverage(rows), jnp.uint16(0)))
+    lifts = ((kind == KIND_ARRAY,
+              lambda: ARRAY_LIFTS[array_lift](rows, card)),
+             ((kind == KIND_RUN) & ~one_run,
+              lambda: jax.vmap(coverage_by_scatter)(rows, nruns)))
+    for pred, lift in lifts:
         def run(b, pred=pred, lift=lift):
             return jnp.where(pred[:, None, None], lift(), b)
 

@@ -39,6 +39,7 @@ from typing import Tuple, Union
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import dispatch as D
 
@@ -49,6 +50,7 @@ __all__ = [
     "FusedPlan", "plan_tape", "plan_cache_size", "plan_stats",
     "word_tape_pallas", "fused_eval_pallas", "fused_eval_ref",
     "LIFT_META_FIELDS", "pack_lift_meta",
+    "group_card_pallas", "group_card_ref",
 ]
 
 # A tree is an operand index (leaf) or an (op, *subtrees) tuple.
@@ -204,16 +206,7 @@ def _tape_kernel(*refs, plan: FusedPlan, leaves: Tuple[Tuple[int, int], ...],
     Intermediates are values of the step and never return to HBM.
     ``leaves[n]`` is the (input, leaf) a tape load of operand ``n`` reads."""
     ins, bits_ref, card_ref = refs[:n_in], refs[n_in], refs[n_in + 1]
-    slots = {}
-    for step in plan.tape:
-        if step[0] == "load":
-            _, n, dst = step
-            k, l = leaves[n]
-            slots[dst] = ins[k][0, l]
-        else:
-            op, sa, sb, dst = step
-            slots[dst] = _WORD_OPS[op](slots[sa], slots[sb])
-    res = slots[0]
+    res = _replay(plan, lambda n: ins[leaves[n][0]][0, leaves[n][1]])
     bits_ref[0] = res
     # per-lane partial cards (a lane-dense block); the caller sums lanes
     card_ref[0] = jnp.sum(jax.lax.population_count(res).astype(jnp.int32),
@@ -265,6 +258,100 @@ def word_tape_pallas(operands, plan: FusedPlan, *, interpret: bool = True):
     return words, jnp.sum(lanes[:, :, 0, :], axis=-1)
 
 
+def _replay(plan: FusedPlan, load):
+    """The root of ``plan``'s tape, with ``load(n)`` the words of operand
+    ``n``."""
+    slots = {}
+    for step in plan.tape:
+        if step[0] == "load":
+            slots[step[2]] = load(step[1])
+        else:
+            op, sa, sb, dst = step
+            slots[dst] = _WORD_OPS[op](slots[sa], slots[sb])
+    return slots[0]
+
+
+def _lane_card(words):
+    """Per-lane popcount of rows of words ``(tb, 16, 128)``: ``(1, 128)``."""
+    per_row = jnp.sum(jax.lax.population_count(words).astype(jnp.int32),
+                      axis=1, keepdims=True)
+    return jnp.sum(per_row, axis=0)
+
+
+def _group_card_kernel(*refs, plan, has_groups: bool):
+    """One block of chunk rows per grid step: the filter (``plan``'s tape
+    over the leaf block), then, for each group and slice, the popcount of
+    ``filter & group & slice``, added into the lane partials that stay in
+    VMEM across the grid."""
+    out_ref, ins = refs[-1], list(refs[:-1])
+    leaves = ins.pop(0) if plan is not None else None
+    groups = ins.pop(0) if has_groups else None
+    slices = ins.pop(0)
+
+    @pl.when(pl.program_id(0) == 0)
+    def _():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    filt = None if plan is None else _replay(plan, lambda n: leaves[n])
+    for g in range(out_ref.shape[0]):
+        sel = filt
+        if groups is not None:
+            sel = groups[g] if sel is None else sel & groups[g]
+        for b in range(out_ref.shape[1]):
+            words = slices[b] if sel is None else slices[b] & sel
+            out_ref[g, b] += _lane_card(words)
+
+
+def group_card_pallas(leaves, groups, slices, plan, *,
+                      interpret: bool = True):
+    """``|filter ∩ group_g ∩ slice_b|`` for every (group, slice) pair in ONE
+    launch: i32[max(G, 1), B].
+
+    leaves: u32[L, C, 16, 128] the bitmap-domain words of the filter's
+    leaves, the operands of ``plan``'s tape (both None: no filter, every
+    row). groups: u32[G, C, 16, 128] (None: one group, every row). slices:
+    u32[B, C, 16, 128]. Grid ``(C/tb,)``: each step reads its ``tb`` chunk
+    rows of every operand once, replays the tape on the leaves, and adds
+    the ``G·B`` popcounts into lane partials held in VMEM across the grid,
+    so no operand is read twice and nothing but the partials is written.
+    Its stable name in a device trace is ``roaring_group_card``.
+    """
+    operands = [o for o in (leaves, groups, slices) if o is not None]
+    C = slices.shape[1]
+    G = 1 if groups is None else groups.shape[0]
+    B = slices.shape[0]
+    tb = _rows_per_step(C, sum(o.shape[0] for o in operands))
+    lanes = pl.pallas_call(
+        functools.partial(_group_card_kernel, plan=plan,
+                          has_groups=groups is not None),
+        grid=(C // tb,),
+        in_specs=[pl.BlockSpec((o.shape[0], tb, *WORD_SHAPE),
+                               lambda j: (0, j, 0, 0)) for o in operands],
+        out_specs=pl.BlockSpec((G, B, 1, 128), lambda j: (0, 0, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((G, B, 1, 128), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="roaring_group_card",
+    )(*operands)
+    return jnp.sum(lanes[:, :, 0, :], axis=-1)
+
+
+def group_card_ref(leaves, groups, slices, plan):
+    """XLA mirror of ``group_card_pallas``: the same tape over whole slot
+    arrays, then one popcount per (group, slice) pair."""
+    filt = None if plan is None else _replay(plan, lambda n: leaves[n])
+    cards = []
+    for g in range(1 if groups is None else groups.shape[0]):
+        sel = filt
+        if groups is not None:
+            sel = groups[g] if sel is None else sel & groups[g]
+        words = slices if sel is None else slices & sel[None]
+        cards.append(jnp.sum(jax.lax.population_count(words).astype(
+            jnp.int32), axis=(1, 2, 3)))
+    return jnp.stack(cards)
+
+
 def fused_eval_pallas(ops_data: jax.Array, meta: jax.Array, *,
                       plan: FusedPlan, interpret: bool = True):
     """Evaluate a whole Boolean tree in ONE Pallas launch.
@@ -292,15 +379,7 @@ def fused_eval_ref(ops_data: jax.Array, meta: jax.Array, *,
     array lift, and the same word ops over whole [C, 16, 128] slot arrays.
     Bit-identical output."""
     leaves = _lift_leaves(ops_data, meta, "scatter")
-    slots = {}
-    for step in plan.tape:
-        if step[0] == "load":
-            _, n, dst = step
-            slots[dst] = leaves[n]
-        else:
-            op, sa, sb, dst = step
-            slots[dst] = _WORD_OPS[op](slots[sa], slots[sb])
-    res = slots[0]
+    res = _replay(plan, lambda n: leaves[n])
     card = jnp.sum(jax.lax.population_count(res).astype(jnp.int32),
                    axis=(1, 2))
     return D.from_words(res), card

@@ -276,3 +276,22 @@ def card_scan(payload, groups, places, codes, q_words, *, width: int,
     use_pallas, interpret = _resolve(use_pallas, interpret, "card_scan")
     return _card_scan(payload, groups, places, codes, q_words, width,
                       bitmaps, use_pallas, interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("plan", "use_pallas",
+                                             "interpret"))
+def _group_card(leaves, groups, slices, plan, use_pallas, interpret):
+    if use_pallas or interpret:
+        return _f.group_card_pallas(leaves, groups, slices, plan,
+                                    interpret=not _d.on_tpu())
+    return _f.group_card_ref(leaves, groups, slices, plan)
+
+
+def group_card(leaves, groups, slices, plan, use_pallas: bool | None = None,
+               interpret: bool = False):
+    """i32[max(G, 1), B] ``|filter ∩ group_g ∩ slice_b|`` over bitmap-domain
+    words (``fused.group_card_pallas`` for the arguments; ``plan`` is
+    static). Pallas kernel ``roaring_group_card`` on TPU, the tape-mirroring
+    XLA evaluator elsewhere."""
+    use_pallas, interpret = _resolve(use_pallas, interpret, "group_card")
+    return _group_card(leaves, groups, slices, plan, use_pallas, interpret)

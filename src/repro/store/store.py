@@ -47,6 +47,7 @@ import repro.obs as obs
 from repro import index as ix
 from repro.core import jax_roaring as jr
 from repro.core import py_roaring as pr
+from repro.kernels.roaring import ops as _kops
 from repro.roaring import RoaringSlab
 from repro.store import predicate as P
 
@@ -102,6 +103,28 @@ def _posting(row_ids: np.ndarray) -> pr.RoaringBitmap:
     kinds are what make store bytes match the engine's query outputs)."""
     return pr.RoaringBitmap.from_sorted_unique(
         np.asarray(row_ids, np.int64)).run_optimize()
+
+
+def _posting_of_mask(mask: np.ndarray) -> pr.RoaringBitmap:
+    """Boolean row mask -> the same canonical host bitmap as ``_posting``
+    of its row ids, with each dense chunk's bitmap words packed straight
+    from the mask (no row ids, no per-value scatter)."""
+    n_chunks = _chunks_for(mask.size)
+    bits = np.zeros(n_chunks * pr.CHUNK_SIZE, bool)
+    bits[: mask.size] = mask
+    bits = bits.reshape(n_chunks, pr.CHUNK_SIZE)
+    words = np.packbits(bits, axis=1, bitorder="little").view("<u8")
+    cards = np.bitwise_count(words).sum(axis=1)
+    keys, containers = [], []
+    for k in np.flatnonzero(cards).tolist():
+        keys.append(k)
+        if cards[k] > pr.ARRAY_MAX:
+            containers.append(pr.BitmapContainer(
+                words[k].astype(np.uint64), int(cards[k])))
+        else:
+            containers.append(pr.ArrayContainer(
+                np.flatnonzero(bits[k]).astype(np.uint16)))
+    return pr.RoaringBitmap(keys, containers).run_optimize()
 
 
 def _stack_bitmaps(bitmaps: Sequence[pr.RoaringBitmap], n_rows: int,
@@ -170,6 +193,40 @@ def _norm_column(name: str, col: np.ndarray):
                     "(store columns hold ints or strings)")
 
 
+def _holds(atom: P.Pred, v) -> bool:
+    """Whether value ``v`` of a column passes an atom on that column."""
+    if isinstance(atom, P.Eq):
+        return v == atom.value
+    if isinstance(atom, P.In):
+        return v in atom.values
+    return ((atom.lo is None or v >= atom.lo)
+            and (atom.hi is None or v <= atom.hi))
+
+
+def _sum_program(plan, n_leaves: int, n_groups: int) -> Callable:
+    """The program of one sum shape: ``(stack, members) -> i32[max(G, 1),
+    B]`` cards, ``members`` the filter's leaves, then the groups, then the
+    summed column's slices. ``store.filter`` names the leaves' lift; the
+    filter's tape runs inside the aggregation kernel, under
+    ``store.aggregate``."""
+
+    def program(stack, members):
+        leaves = groups = None
+        if plan is not None:
+            with jax.named_scope("store.filter"):
+                leaves = ix.lift_members(stack, members[:n_leaves])
+        with jax.named_scope("store.aggregate"):
+            # lifted apart: a slice of one lift would be copied for the
+            # kernel
+            if n_groups:
+                groups = ix.lift_members(
+                    stack, members[n_leaves:n_leaves + n_groups])
+            slices = ix.lift_members(stack, members[n_leaves + n_groups:])
+            return ix.pair_cards(plan, leaves, groups, slices)
+
+    return program
+
+
 class BitmapStore:
     """Per-(column, value) Roaring bitmap index over columnar records."""
 
@@ -236,12 +293,17 @@ class BitmapStore:
                                      "values")
                 bits = max(1, int(arr.max()).bit_length()) if n_rows else 1
                 columns.append(BsiColumn(name, bits, len(bitmaps)))
+                narrow = arr.astype(np.min_scalar_type((1 << bits) - 1))
                 for j in range(bits):
-                    rows = np.nonzero((arr >> j) & 1)[0]
-                    bitmaps.append(_posting(rows))
+                    bitmaps.append(_posting_of_mask(((narrow >> j) & 1) > 0))
             else:
                 # stable argsort groups equal values with ascending row ids
-                order = np.argsort(arr, kind="stable")
+                # (numpy radix-sorts 16-bit keys)
+                key = arr
+                if (vkind == "int" and n_rows
+                        and int(arr.max()) - int(arr.min()) < (1 << 16)):
+                    key = (arr - arr.min()).astype(np.uint16)
+                order = np.argsort(key, kind="stable")
                 svals = arr[order]
                 if n_rows:
                     bounds = np.nonzero(svals[1:] != svals[:-1])[0] + 1
@@ -254,7 +316,7 @@ class BitmapStore:
                 for s, e in zip(starts.tolist(), ends.tolist()):
                     v = svals[s]
                     values.append(int(v) if vkind == "int" else str(v))
-                    bitmaps.append(_posting(np.sort(order[s:e])))
+                    bitmaps.append(_posting(order[s:e]))
                 columns.append(EqColumn(name, vkind, tuple(values), base))
         return cls(n_rows, columns, bitmaps)
 
@@ -409,10 +471,12 @@ class BitmapStore:
         reg.gauge("store.query_cache.entries",
                   store=sid).set(len(self._query_fns))
 
-    def _run_cached(self, key: Tuple, make_fn: Callable, eager_fn: Callable):
+    def _run_cached(self, key: Tuple, make_fn: Callable, eager_fn: Callable,
+                    *args):
         """One cached-executor run: cache lookup (hit/miss accounting), the
-        jitted call under a ``store.execute`` span, and the eager-ladder
-        fallback (its own span + counter) when the jitted call fails."""
+        jitted call on ``(stack, *args)`` under a ``store.execute`` span,
+        and the eager-ladder fallback (its own span + counter) when the
+        jitted call fails."""
         fn = self._query_fns.get(key)
         if fn is None:
             # jax.jit wrapping is lazy: the actual trace+compile cost lands
@@ -426,7 +490,7 @@ class BitmapStore:
             cache = "hit"
         try:
             with obs.span("store.execute", cache=cache):
-                return fn(self._stack)
+                return fn(self._stack, *args)
         except Exception:
             self._cache_fallbacks += 1
             with obs.span("store.fallback_eager"):
@@ -486,20 +550,88 @@ class BitmapStore:
         return self.query(pred, **kw).to_roaring().to_array()
 
     def sum_(self, name: str, pred: Optional[P.Pred] = None) -> int:
-        """Sum of a bit-sliced column over the rows matching ``pred``
-        (all rows when ``None``): Σ_j 2^j · |slice_j ∩ rows| — one batched
-        scoring launch over the column's slices, nothing materialized per
-        bit."""
+        """Sum of a bit-sliced column over the rows matching ``pred`` (all
+        rows when ``None``): ``group_sum``'s program with one group of every
+        row."""
+        return self._sum(name, None, pred)[None]
+
+    def group_sum(self, name: str, by: str,
+                  pred: Optional[P.Pred] = None) -> Dict[object, int]:
+        """``{v: Σ name over the rows matching pred with by == v}`` for every
+        stored value ``v`` of the equality column ``by``: a sum grouped by
+        one column (FeatureBase's ``GroupBy(Rows(by), aggregate=Sum(name))``).
+
+        One jitted program per (predicate shape, groups, slices) takes
+        ``|filter ∩ {by = v} ∩ slice_j|`` for every group and bit slice
+        (``index.pair_cards``): the filter runs as a fused tape inside the
+        aggregation kernel and its result is never built. The host weighs
+        the i32 cards by ``2^j`` in Python ints, so sums are exact past
+        2^31. Atoms of a top-level AND on ``by`` itself pick the groups
+        read (the others sum to 0) and leave the filter.
+        """
+        if not isinstance(self.column(by), EqColumn):
+            raise TypeError(f"group_sum groups by an equality column; {by!r} "
+                            "is bit-sliced")
+        return self._sum(name, by, pred)
+
+    def _sum(self, name: str, by: Optional[str],
+             pred: Optional[P.Pred]) -> Dict[object, int]:
         col = self.column(name)
         if not isinstance(col, BsiColumn):
-            raise TypeError(f"sum_ needs a bit-sliced column; {name!r} is "
-                            "an equality column")
-        rows = self.query(pred) if pred is not None else \
-            ix.execute(self._stack, ix.leaf(UNIVERSE_SLOT))
-        slots = jnp.arange(col.base_slot, col.base_slot + col.bits)
-        per_bit = np.asarray(ix.batched_and_card(self._stack[slots], rows))
-        weights = np.asarray([1 << j for j in range(col.bits)], np.int64)
-        return int(per_bit.astype(np.int64) @ weights)
+            raise TypeError(f"sums need a bit-sliced column; {name!r} is an "
+                            "equality column")
+        with obs.span("store.sum", column=name, by=by) as sp:
+            with obs.span("store.compile"):
+                values, groups, pred = self._groups(by, pred)
+                plan, leaves = (None, ()) if pred is None else \
+                    ix.lower(self.compile(pred))
+            slices = tuple(range(col.base_slot, col.base_slot + col.bits))
+            L, G, B = len(leaves), len(groups), len(slices)
+            # the predicate may rule out every group: nothing is read
+            rows_read = (L + G + B) * self.n_chunks if values else 0
+            for k, v in (("groups", G), ("slices", B), ("leaves", L),
+                         ("rows_read", rows_read)):
+                sp.set_attr(k, v)
+            sums = dict.fromkeys(
+                (None,) if by is None else self.column(by).values, 0)
+            if not values:
+                return sums
+            obs.registry().counter("store.rows_read").inc(rows_read)
+            members = jnp.asarray(leaves + groups + slices, jnp.int32)
+            program = _sum_program(plan, L, G)
+
+            def eager():
+                with _kops.backend_scope("xla"):
+                    return program(self._stack, members)
+
+            cards = self._run_cached(("sum", plan, L, G, B),
+                                     lambda: jax.jit(program), eager, members)
+            with obs.span("store.sync"):
+                cards = np.asarray(cards).tolist()
+        for v, row in zip(values, cards):
+            sums[v] = sum(c << j for j, c in enumerate(row))
+        return sums
+
+    def _groups(self, by: Optional[str], pred: Optional[P.Pred]):
+        """``(values, slots, filter)``: the values of ``by`` whose groups are
+        read, their slots, and the predicate left to filter by (no ``by``:
+        one group of every row, ``(None,)``, with no slot).
+        Atoms on ``by`` in a top-level AND (or ``pred`` itself) pick the
+        groups: the values outside them sum to 0, and for those inside the
+        atom holds on every row of the group, so it leaves the filter."""
+        if by is None:
+            return (None,), (), pred
+        col = self.column(by)
+        atoms = () if pred is None else (
+            pred.children if isinstance(pred, P.AndP) else (pred,))
+        on_by = [a for a in atoms if isinstance(a, (P.Eq, P.In, P.Range))
+                 and a.col == by]
+        rest = [a for a in atoms if not any(a is b for b in on_by)]
+        for a in on_by:
+            self.compile(a)             # the same type checks as a query
+        values = [v for v in col.values if all(_holds(a, v) for a in on_by)]
+        groups = tuple(self._eq_slot[(by, v)] for v in values)
+        return values, groups, P.and_(*rest) if rest else None
 
     def __repr__(self) -> str:
         parts = ", ".join(

@@ -349,6 +349,26 @@ def test_lift_rows_every_kind(array_lift):
     np.testing.assert_array_equal(np.asarray(D.from_words(got)), want)
 
 
+@pytest.mark.parametrize("start,end", [(0, 65536), (0, 1), (65535, 65536),
+                                       (15, 17), (16, 32), (7, 40000)])
+def test_lift_rows_one_run_rows(start, end):
+    """A row of one run is covered in place: runs over a whole row, one
+    value at either end, and runs that start or end inside a word or on
+    its edge, beside a many-run row in the same lift."""
+    rows = np.full((2, jr.ROW_WORDS), 0xFFFF, np.uint16)
+    rows[0, :2] = start, end - start - 1            # (start, length - 1)
+    rows[1, :4] = 3, 5, 20, 4449
+    kinds = np.full(2, jr.KIND_RUN, np.int32)
+    cards = np.asarray([end - start, 6 + 4450], np.int32)
+    nruns = np.asarray([1, 2], np.int32)
+    got = D.lift_rows(jnp.asarray(rows), jnp.asarray(kinds),
+                      jnp.asarray(cards), jnp.asarray(nruns), "scatter")
+    want = _bitmap_rows([np.arange(start, end),
+                         np.concatenate([np.arange(3, 9),
+                                         np.arange(20, 4470)])])
+    np.testing.assert_array_equal(np.asarray(D.from_words(got)), want)
+
+
 def test_stacked_dispatch_shared_query_matches_broadcast():
     """A query slab shared by all N stacked slabs (u16[C, 4096]) gives the
     same hits and cards as the query broadcast to every slab, on the Pallas

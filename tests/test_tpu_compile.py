@@ -273,3 +273,44 @@ def test_planned_scoring_compiles_for_v5e(one_chip, chip_backend, chunks):
         shape = [int(d) for d in dims.split(",")]
         if shape[-2:] == [16, 128]:
             assert np.prod(shape[:-2]) < N * chunks, shape
+
+
+def test_store_sum_compiles_for_v5e(one_chip, chip_backend):
+    """The store's aggregation program at the ``ssb-q1`` cell's shape: 183
+    slabs of 916 chunk rows, SSB Q1.1's filter (a ``d_year`` slab and
+    ``lo_quantity < 25`` over its 6 slices and the universe), 3 discount
+    groups and the 24 price slices, in one ``roaring_group_card`` launch.
+    The temporaries (0.89 GB at this shape) are the gathered and lifted
+    rows of the 35 slabs; no op reads the whole stack."""
+    from repro import index as ix
+    from repro import store
+    from repro.roaring import RoaringSlab
+    from repro.store import store as S
+
+    rng = np.random.default_rng(0)
+    n = 5_000
+    tiny = store.BitmapStore.build(
+        {"d_year": rng.integers(1992, 1999, n),
+         "lo_discount": rng.integers(0, 11, n),
+         "lo_quantity": rng.integers(1, 51, n),
+         "lo_extendedprice": rng.integers(0, 1 << 24, n)},
+        bsi=("lo_quantity", "lo_extendedprice"))
+    plan, leaves = ix.lower(tiny.compile(store.and_(
+        store.eq("d_year", 1993), store.range_("lo_quantity", None, 24))))
+    assert len(leaves) == 8
+    N, chunks, G, B = 183, 916, 3, 24
+
+    def i32(*shape):
+        return _spec(shape, jnp.int32, one_chip)
+
+    stack = RoaringSlab(
+        payload=_spec((N, chunks, ROW), jnp.uint16, one_chip),
+        keys=i32(N, chunks), kinds=i32(N, chunks), cards=i32(N, chunks),
+        nruns=i32(N, chunks), C=chunks)
+    compiled = jax.jit(S._sum_program(plan, len(leaves), G)).lower(
+        stack, i32(len(leaves) + G + B)).compile()
+    text = compiled.as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+    assert "roaring_group_card" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert "mini-gather" not in text
